@@ -1,31 +1,26 @@
 """AOT program cache: no first-use XLA compile on a serving path.
 
-Two artifact layers, consulted by ``resolve(name)`` in order:
+``resolve(name)`` returns the executable :func:`warmup` produced for a
+program name in the compile manifest
+(``tools/artifacts/aot/compile_manifest.json``, emitted by ``python -m
+tools.graftlint --emit-compile-manifest`` and machine-checked by GL16),
+or None — the caller then dispatches its plain ``jax.jit``.  After
+warmup every serving-path dispatch in device.py finds its program here
+and never traces.
 
-1. **In-process table** (``_compiled``) — executables produced by
-   :func:`warmup` at node startup, one per program name in the
-   compile manifest (``tools/artifacts/aot/compile_manifest.json``,
-   emitted by ``python -m tools.graftlint --emit-compile-manifest``
-   and machine-checked by GL16).  After warmup every serving-path
-   dispatch in device.py finds its program here and never traces.
+:func:`warmup` is backed by a **content-addressed on-disk executable
+cache** keyed on (jaxlib version, program hash, bucket tuple): a node
+restart deserializes yesterday's executables instead of re-burning
+minutes of XLA time per pairing-shaped program.  It lives in the
+``aot`` subdirectory of the compile-cache root (:func:`cache_root`),
+the same directory JAX's persistent cache uses, so one directory set
+from outside (``JAX_COMPILATION_CACHE_DIR``) holds both.
 
-2. **Shipped jax.export artifacts** (``tools/artifacts/aot/
-   <name>.jaxexport[.gz]``, written by tools/aot_export.py) — the
-   legacy load-and-call route: first device contact compiles from
-   the artifact's StableHLO instead of re-tracing Python.
-
-:func:`warmup` itself is backed by a **content-addressed on-disk
-executable cache** (``$HARMONY_AOT_CACHE`` or ``<repo>/.aot_cache``)
-keyed on (jaxlib version, program hash, bucket tuple): a node restart
-— or the multichip dryrun — deserializes yesterday's executables in
-milliseconds instead of re-burning minutes of XLA time (PR 15's
-NEWVIEW wedge, MULTICHIP_r05's 3m21s compile burn).
-
-Failures never take a node down: every layer falls back to plain
-``jax.jit`` — but no longer *silently*.  Each failed artifact logs
-once and counts ``harmony_aot_fallback_total{reason}``; cache traffic
-counts ``harmony_aot_cache_total{event}`` (hit / miss / store /
-corrupt / skew).
+Failures never take a node down: warmup falls back to plain
+``jax.jit`` — but not *silently*.  Each failed program logs once and
+counts ``harmony_aot_fallback_total{reason}``; cache traffic counts
+``harmony_aot_cache_total{event}`` (hit / miss / store / corrupt /
+skew).
 """
 
 from __future__ import annotations
@@ -37,6 +32,7 @@ import pickle
 import re
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 from .log import get_logger
 from .metrics import Counter
@@ -44,8 +40,8 @@ from .metrics import Counter
 log = get_logger("aot")
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_EXPORT_DIR = os.path.join(_REPO_ROOT, "tools", "artifacts", "aot")
-MANIFEST_PATH = os.path.join(_EXPORT_DIR, "compile_manifest.json")
+MANIFEST_PATH = os.path.join(_REPO_ROOT, "tools", "artifacts", "aot",
+                             "compile_manifest.json")
 
 FALLBACKS = Counter(
     "harmony_aot_fallback_total",
@@ -56,8 +52,7 @@ CACHE_EVENTS = Counter(
     "content-addressed executable-cache events, by event",
 )
 
-_compiled: dict = {}      # program name -> warmed executable/callable
-_export_cache: dict = {}  # program name -> jax.export call (or None)
+_compiled: dict = {}  # program name -> warmed executable/callable
 _warned: set = set()
 _lock = threading.Lock()
 
@@ -69,9 +64,9 @@ def expose() -> str:
 
 
 def _fallback(name: str, reason: str, detail: str) -> None:
-    """Record a failed artifact exactly once per (name, reason):
-    the old ``except Exception: pass`` here turned corrupt or
-    version-skewed artifacts into silent minutes-long jit burns."""
+    """Record a failed program exactly once per (name, reason): a
+    silent fallback turns a corrupt or version-skewed artifact into an
+    unexplained minutes-long jit burn."""
     FALLBACKS.inc(reason=reason)
     key = (name, reason)
     with _lock:
@@ -82,61 +77,16 @@ def _fallback(name: str, reason: str, detail: str) -> None:
              artifact=name, reason=reason, detail=detail)
 
 
-# ---------------------------------------------------------------------------
-# layer 2: shipped jax.export artifacts (legacy load-and-call)
-# ---------------------------------------------------------------------------
-
-def load(name: str):
-    """The exported program's ``call`` for ``name`` (e.g.
-    ``agg_verify_b8``), or None when no artifact is shipped."""
-    with _lock:
-        if name in _export_cache:
-            return _export_cache[name]
-    call = None
-    for suffix in (".jaxexport", ".jaxexport.gz"):
-        path = os.path.join(_EXPORT_DIR, name + suffix)
-        if not os.path.exists(path):
-            continue
-        try:
-            if suffix.endswith(".gz"):
-                import gzip
-
-                with gzip.open(path, "rb") as f:
-                    blob = f.read()
-            else:
-                with open(path, "rb") as f:
-                    blob = f.read()
-        except OSError as e:
-            _fallback(name, "io", f"{path}: {e}")
-            continue
-        try:
-            from jax import export as jexport
-
-            call = jexport.deserialize(blob).call
-            break
-        except Exception as e:  # noqa: BLE001 — stale/foreign artifact
-            _fallback(name, "corrupt", f"{path}: {e!r}")
-            call = None
-    with _lock:
-        _export_cache[name] = call
-    return call
-
-
 def resolve(name: str):
-    """The strongest available callable for ``name``: the warmed
-    executable if startup warmup ran, else a shipped jax.export
-    artifact, else None (caller dispatches its plain jit fn)."""
+    """The warmed executable for ``name``, or None when startup warmup
+    did not produce one (the caller dispatches its plain jit fn)."""
     with _lock:
-        fn = _compiled.get(name)
-    if fn is not None:
-        return fn
-    return load(name)
+        return _compiled.get(name)
 
 
 def _reset_for_tests() -> None:
     with _lock:
         _compiled.clear()
-        _export_cache.clear()
         _warned.clear()
 
 
@@ -144,9 +94,30 @@ def _reset_for_tests() -> None:
 # content-addressed executable cache
 # ---------------------------------------------------------------------------
 
+def cache_root() -> str:
+    """The one compile-cache directory: ``$JAX_COMPILATION_CACHE_DIR``
+    when set from outside, else the fixed ``<repo>/.jax_cache`` (a
+    fixed path, because the path is part of what a cache hit needs)."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_REPO_ROOT, ".jax_cache"))
+
+
 def cache_dir() -> str:
-    return os.environ.get("HARMONY_AOT_CACHE") or os.path.join(
-        _REPO_ROOT, ".aot_cache")
+    """The AOT executable cache, inside :func:`cache_root`."""
+    return os.path.join(cache_root(), "aot")
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at :func:`cache_root`.
+    Every entry point calls this before its first compile.  When
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and no
+    cache directory is set in code."""
+    root = cache_root()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", root)
+    return root
 
 
 def jaxlib_version() -> str:
@@ -297,8 +268,8 @@ _FAMILY_RES = (
 
 def program_spec(name: str):
     """(family, bucket-tuple, arg ShapeDtypeStructs) for a manifest
-    program name; None for an unrecognized name.  Shapes mirror
-    tools/aot_export.py — int32 limbs throughout."""
+    program name; None for an unrecognized name.  Shapes are the
+    device.py call sites' — int32 limbs throughout."""
     import jax
     import jax.numpy as jnp
 
@@ -340,42 +311,71 @@ def _family_fn(family: str):
     }[family]()
 
 
+# Peak host memory of one pairing-shaped TPU compile, rounded up: the
+# off-chip rehearsal of agg_verify_b256 (PR 21) reached 6.4 GB RSS.
+_COMPILE_HOST_BYTES = 8 << 30
+
+
+def _compile_workers(n_cold: int) -> int:
+    """Concurrent XLA compiles: one per core, but never more than host
+    memory holds at one pairing-sized compile each.  XLA compiles a
+    program on about one core and releases the GIL while it does, so
+    cold programs compile side by side."""
+    try:
+        mem = os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+    except (ValueError, OSError):
+        mem = _COMPILE_HOST_BYTES
+    return max(1, min(n_cold, os.cpu_count() or 1,
+                      mem // _COMPILE_HOST_BYTES))
+
+
 # graftlint: compile-phase=warmup
-def _warm_one(name: str, backend: str) -> tuple:
-    """Materialize one manifest program into ``_compiled``: disk-cache
-    deserialize when warm, lower+compile+store when cold.  Returns
-    ("cached"|"compiled"|"failed", seconds-of-XLA-compile)."""
+def _lower_one(name: str, backend: str):
+    """Trace and lower one manifest program and look it up in the disk
+    cache.  Returns ("cached", None) after parking a cache hit in
+    ``_compiled``, ("cold", (lowered, key, meta)) on a miss, or
+    ("failed", None)."""
     spec = program_spec(name)
     if spec is None:
         _fallback(name, "unknown-program",
                   "manifest name matches no program family")
-        return "failed", 0.0
+        return "failed", None
     family, dims, arg_specs = spec
     try:
-        fn = _family_fn(family)
-        lowered = fn.lower(*arg_specs)
+        lowered = _family_fn(family).lower(*arg_specs)
         program_sha = hashlib.sha256(
             lowered.as_text().encode()).hexdigest()
         key = cache_key(program_sha, dims, backend)
         loaded = cache_load(key, name)
-        if loaded is not None:
-            with _lock:
-                _compiled[name] = loaded
-            return "cached", 0.0
-        t0 = time.monotonic()
-        compiled = lowered.compile()
-        dt = time.monotonic() - t0
-        cache_store(key, compiled, {
-            "program": name, "bucket": list(dims),
-            "jaxlib": jaxlib_version(), "backend": backend,
-            "program_sha": program_sha,
-        })
+    except Exception as e:  # noqa: BLE001 — warmup must not kill boot
+        _fallback(name, "warmup", repr(e))
+        return "failed", None
+    if loaded is not None:
         with _lock:
-            _compiled[name] = compiled
-        return "compiled", dt
+            _compiled[name] = loaded
+        return "cached", None
+    return "cold", (lowered, key, {
+        "program": name, "bucket": list(dims),
+        "jaxlib": jaxlib_version(), "backend": backend,
+        "program_sha": program_sha,
+    })
+
+
+# graftlint: compile-phase=warmup
+def _compile_one(name: str, lowered, key: str, meta: dict) -> tuple:
+    """Compile a cold program, store it, park it for ``resolve``.
+    Returns ("compiled"|"failed", seconds-of-XLA-compile)."""
+    t0 = time.monotonic()
+    try:
+        compiled = lowered.compile()
     except Exception as e:  # noqa: BLE001 — warmup must not kill boot
         _fallback(name, "warmup", repr(e))
         return "failed", 0.0
+    dt = time.monotonic() - t0
+    cache_store(key, compiled, dict(meta, compile_s=round(dt, 3)))
+    with _lock:
+        _compiled[name] = compiled
+    return "compiled", dt
 
 
 def warmup(manifest: dict | None = None) -> dict:
@@ -389,8 +389,13 @@ def warmup(manifest: dict | None = None) -> dict:
         hot path) is marked warm so JIT first-use counters stay flat.
       * XLA:CPU, no twin — device.py dispatches everything eagerly
         (``_fused()`` is False); nothing to compile.
-      * accelerator — lower/compile (or disk-cache load) every
-        manifest program and park the executables for ``resolve``.
+      * accelerator — lower every manifest program in turn (tracing
+        holds the GIL), load it from the disk cache or hand it to a
+        pool that compiles the cold ones side by side, and park the
+        executables for ``resolve``.
+
+    ``stats["per_program"]`` maps each name to its outcome
+    (cached / compiled / failed) and its lower and compile seconds.
     """
     from . import device as DV
 
@@ -399,7 +404,7 @@ def warmup(manifest: dict | None = None) -> dict:
     names = manifest_names(manifest)
     stats = {"mode": "eager", "programs": len(names), "warmed": 0,
              "cached": 0, "compiled": 0, "failed": 0,
-             "compile_s": 0.0, "saved_s": 0.0}
+             "compile_s": 0.0, "saved_s": 0.0, "per_program": {}}
     if manifest is None:
         stats["mode"] = "no-manifest"
         log.warn("aot warmup: no compile manifest — serving paths may "
@@ -417,20 +422,37 @@ def warmup(manifest: dict | None = None) -> dict:
         return stats
     import jax
 
+    use_compile_cache()
     backend = jax.default_backend()
     stats["mode"] = backend
-    for name in names:
-        outcome, dt = _warm_one(name, backend)
-        stats[outcome] += 1
-        stats["compile_s"] += dt
-        if outcome != "failed":
+    per = stats["per_program"]
+    pool = ThreadPoolExecutor(max_workers=_compile_workers(len(names)),
+                              thread_name_prefix="aot-compile")
+    pending = {}
+    try:
+        for name in names:
+            t0 = time.monotonic()
+            outcome, cold = _lower_one(name, backend)
+            per[name] = {"outcome": outcome,
+                         "lower_s": time.monotonic() - t0,
+                         "compile_s": 0.0}
+            if outcome == "cold":
+                pending[name] = pool.submit(_compile_one, name, *cold)
+        for name, fut in pending.items():
+            per[name]["outcome"], per[name]["compile_s"] = fut.result()
+    finally:
+        pool.shutdown(wait=True)
+    for name, rec in per.items():
+        stats[rec["outcome"]] += 1
+        stats["compile_s"] += rec["compile_s"]
+        if rec["outcome"] != "failed":
             stats["warmed"] += 1
             DV.mark_warm(name)
     # compile seconds a warm disk cache avoided, estimated from this
     # run's own mean compile time (exact when the cache was cold)
     if stats["compiled"]:
-        per = stats["compile_s"] / stats["compiled"]
-        stats["saved_s"] = per * stats["cached"]
+        mean = stats["compile_s"] / stats["compiled"]
+        stats["saved_s"] = mean * stats["cached"]
     return stats
 
 

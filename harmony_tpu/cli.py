@@ -17,11 +17,7 @@ import os
 import signal
 import sys
 import threading
-
-try:
-    import tomllib
-except ImportError:  # Python < 3.11: tomli is the same parser
-    import tomli as tomllib
+import tomllib
 
 from .chain.engine import Engine, EpochContext
 from .config.chain import ChainConfig

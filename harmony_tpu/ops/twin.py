@@ -8,7 +8,7 @@ when loaded, bigint otherwise) instead of XLA.  Two consumers:
   switch, so a LIVE node can exercise every device-path layer —
   CommitteeTable padding, bitmap routing, counters, batch chunking —
   on a box where executing the pairing through XLA:CPU is measured in
-  minutes (docs/NOTES_r2.md).  The kernel math itself is covered by
+  minutes.  The kernel math itself is covered by
   the ops parity tier; this preserves the layer split of
   tests/test_device_path.py for live runs (VERDICT r4 #3).
 * tests, as hermetic stand-ins with call accounting.

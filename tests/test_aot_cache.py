@@ -3,8 +3,9 @@
 Covers ISSUE 17's satellite contract: cache hit / miss / corrupt-
 artifact / version-skew behavior of harmony_tpu.aot, the once-per-
 artifact fallback logging with ``harmony_aot_fallback_total{reason}``,
-resolve() precedence, twin-mode warmup marking, and the committed
-compile manifest's shape.  The one real executable these tests
+resolve() returning only warmed executables, the cache's place under
+the one compile-cache root, concurrent warmup, twin-mode warmup
+marking, and the committed compile manifest's shape.  The one real executable these tests
 serialize is a scalar add — nothing pairing-shaped ever compiles.
 """
 
@@ -26,7 +27,7 @@ from harmony_tpu import aot  # noqa: E402
 
 @pytest.fixture(autouse=True)
 def _isolated_cache(tmp_path, monkeypatch):
-    monkeypatch.setenv("HARMONY_AOT_CACHE", str(tmp_path / "aotc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     aot._reset_for_tests()
     yield
     aot._reset_for_tests()
@@ -104,22 +105,71 @@ def test_version_skew_detected_on_miss(monkeypatch):
     assert _counts(aot.FALLBACKS, reason="skew") == fb0 + 1
 
 
-def test_load_corrupt_export_counts_and_warns_once(tmp_path,
-                                                  monkeypatch):
-    """The old load() swallowed every exception into silent jit
-    fallback; now a corrupt shipped artifact counts a reason and the
-    warn fires once per artifact."""
-    monkeypatch.setattr(aot, "_EXPORT_DIR", str(tmp_path))
-    name = "broken_b8"
-    with open(tmp_path / f"{name}.jaxexport", "wb") as f:
-        f.write(b"\x00garbage")
-    fb0 = _counts(aot.FALLBACKS, reason="corrupt")
-    assert aot.load(name) is None
-    assert _counts(aot.FALLBACKS, reason="corrupt") == fb0 + 1
-    assert (name, "corrupt") in aot._warned
-    # cached negative result: second call doesn't re-read or re-count
-    assert aot.load(name) is None
-    assert _counts(aot.FALLBACKS, reason="corrupt") == fb0 + 1
+def test_cache_dir_inside_compile_cache_root(tmp_path, monkeypatch):
+    """One directory set from outside holds JAX's persistent cache and
+    the AOT executables; unset, both live at the fixed in-repo path."""
+    assert aot.cache_root() == str(tmp_path / "jc")
+    assert aot.cache_dir() == os.path.join(str(tmp_path / "jc"), "aot")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert aot.cache_root() == str(REPO / ".jax_cache")
+
+
+def test_resolve_unwarmed_is_none_and_reads_no_artifact(monkeypatch):
+    """A program warmup did not produce resolves to None (the caller
+    runs its plain jit) — never to a committed export blob, which would
+    run a program traced by another JAX from other code."""
+    import builtins
+
+    opened = []
+    real_open = builtins.open
+
+    def spy(path, *a, **k):
+        opened.append(str(path))
+        return real_open(path, *a, **k)
+
+    monkeypatch.setattr(builtins, "open", spy)
+    assert aot.resolve("agg_verify_b256") is None
+    assert aot.resolve("verify_w8") is None
+    assert not [p for p in opened if "artifacts" in p], opened
+    art = REPO / "tools" / "artifacts" / "aot"
+    assert not list(art.glob("*.jaxexport*"))
+
+
+def test_warmup_compiles_cold_programs_concurrently_then_hits(
+        monkeypatch):
+    """The accelerator branch of warmup, with scalar programs standing
+    in for the pairing families: a cold pass compiles and stores each
+    program (through the pool), a second pass after a restart loads
+    every one from the disk cache, and neither counts a fallback."""
+    import jax
+    import jax.numpy as jnp
+
+    from harmony_tpu import device as DV
+
+    monkeypatch.setattr(DV, "_fused", lambda: True)
+    monkeypatch.setattr(aot, "program_spec", lambda name: (
+        "tiny", (int(name.rsplit("_b", 1)[1]),),
+        (jax.ShapeDtypeStruct((), jnp.int32),)))
+    monkeypatch.setattr(aot, "_family_fn",
+                        lambda fam: jax.jit(lambda x: x * 3 + 1))
+    manifest = {"programs": [{"family": "tiny_b{}",
+                              "names": ["tiny_b8", "tiny_b16"]}]}
+    fb0 = sum(aot.FALLBACKS.value(reason=r) for r in
+              ("warmup", "store", "corrupt", "skew"))
+    cold = aot.warmup(manifest)
+    assert cold["compiled"] == 2 and cold["failed"] == 0
+    assert {n: r["outcome"] for n, r in cold["per_program"].items()} == {
+        "tiny_b8": "compiled", "tiny_b16": "compiled"}
+    import numpy as np
+
+    assert int(np.asarray(aot.resolve("tiny_b8")(np.int32(4)))) == 13
+    aot._reset_for_tests()
+    warm = aot.warmup(manifest)
+    assert warm["cached"] == 2 and warm["compiled"] == 0
+    assert int(np.asarray(aot.resolve("tiny_b16")(np.int32(2)))) == 7
+    assert fb0 == sum(aot.FALLBACKS.value(reason=r) for r in
+                      ("warmup", "store", "corrupt", "skew"))
+    assert aot._compile_workers(3) >= 1
 
 
 def test_resolve_prefers_warmed_executable(monkeypatch):
@@ -127,8 +177,6 @@ def test_resolve_prefers_warmed_executable(monkeypatch):
     with aot._lock:
         aot._compiled["warm_b8"] = sentinel
     assert aot.resolve("warm_b8") is sentinel
-    # unknown name falls through to the export layer (absent -> None)
-    assert aot.resolve("nonexistent_b8") is None
 
 
 def test_warmup_twin_marks_manifest(monkeypatch):

@@ -8,7 +8,7 @@ bitwise-compared against the host bigint path.
 The innermost jitted kernels (ops/bls.agg_verify + friends) are
 swapped for BIGINT-BACKED TWINS here: on this 1-core CI box ANY
 execution of the pairing through XLA — jit compile OR eager — costs
-8+ minutes (measured 2026-07-29; docs/NOTES_r2.md's minefield), so the
+8+ minutes (measured 2026-07-29), so the
 kernel math is covered by the ops parity tier while THIS module covers
 every layer above it: the twins receive exactly the padded device
 arrays the real kernels would, convert them back, and make REAL

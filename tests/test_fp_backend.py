@@ -8,7 +8,7 @@ the scan path at each tier the fast suite can afford on this box:
 raw mont_mul (incl. the lane-padding path), the Fp2/Fp12 towers, and a
 G1 point-double.  The full-pairing GT comparison lives in the isolated
 heavy tier (test_ops_heavy_isolated.py) because any pairing-shaped
-program costs 20+ min of XLA:CPU compile here (docs/NOTES_r3.md).
+program costs 20+ min of XLA:CPU compile here.
 """
 
 import numpy as np

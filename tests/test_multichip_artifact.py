@@ -1,10 +1,10 @@
 """CI diff of the fused multichip lowering artifact (VERDICT r3 #7).
 
 Regenerates the StableHLO summary of ``sharded_agg_verify`` lowered for
-an 8-virtual-device mesh and diffs it against the checked-in artifact —
-a sharding or shape regression in parallel/mesh.py (or anywhere in the
-ops tier the program includes) fails here WITHOUT executing the
-program, which no box below a real 8-chip mesh can afford.  Lowering is
+an 8-virtual-device mesh — its entry signature and its collectives —
+and diffs it against the checked-in artifact: a mesh, sharding or
+collective regression in parallel/mesh.py fails here WITHOUT executing
+the program, which no box below a real 8-chip mesh can afford.  Lowering is
 tracing + StableHLO emission only (no LLVM): ~2-3 min on the 1-core
 box.  Set MULTICHIP_ARTIFACT=0 to skip locally.
 """

@@ -56,7 +56,18 @@ def _run_module(name: str, attempts: int = 2):
     pytest.fail(f"crashed {attempts}x: {last_crash}")
 
 
+@pytest.mark.slow
 def test_ops_pairing_bls_isolated():
+    """Out of the tier-1 budget (PR 21).  Under jaxlib 0.9.0, XLA:CPU
+    crashes (SIGSEGV or SIGABRT in backend_compile_and_load) compiling
+    the eager final-exponentiation scan (ops/pairing.py
+    _cyclo_pow_abs) the second time, at a new batch shape, in one
+    process — four full-module runs out of four, with the stack limit
+    raised and at -O0 alike; the same test alone passes (320 s).  The
+    first three of its eight tests already take ~10 min of XLA:CPU
+    compile, so even crash-free the module would not fit the 1470 s
+    tier-1 limit (extrapolated).  These kernels' decisions are checked
+    against ref/ on the v5e by chip_smoke.py."""
     _run_module("test_ops_pairing_bls.py")
 
 

@@ -1,7 +1,7 @@
 """Fast-tier EXECUTION of the Miller-loop step kernels (VERDICT r3 #5).
 
 The full pairing program cannot compile inside the fast tier on this
-box (20+ min of XLA:CPU, docs/NOTES_r3.md), which left a hole: an edit
+box (20+ min of XLA:CPU), which left a hole: an edit
 breaking ops/pairing.py math kept the quick suite green.  Three layers
 now close it:
 

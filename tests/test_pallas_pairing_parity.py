@@ -1,14 +1,13 @@
 """Full-pairing GT parity: FP_BACKEND scan vs pallas (VERDICT r3 #2).
 
 Opt-in (OPS_PALLAS_PAIRING=1): a full pairing program costs 20+ minutes
-of XLA:CPU compile on the 1-core box (docs/NOTES_r3.md), and interpret-
+of XLA:CPU compile on the 1-core box, and interpret-
 mode Pallas multiplies that further.  The fast tier already proves the
 two backends bit-identical at every composable tier (mont_mul incl.
 lane padding, Fp2/Fp12 towers, the group law — tests/test_fp_backend.py);
 since fp.mont_mul is the ONLY primitive the flag swaps, identical
 mont_mul on all shapes implies identical GT elements.  This test checks
-that implication end-to-end when the budget allows (always on a real
-TPU, where compiles are seconds).
+that implication end-to-end when the budget allows.
 """
 
 import os

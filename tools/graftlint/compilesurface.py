@@ -9,7 +9,7 @@ the jit surface as an enumerable, machine-checked artifact:
 
   GL15  bucket derivability — every *program site* (an f-string program
         name flowing into ``device._program_first_use`` or an
-        ``aot.load/resolve/compiled/warm`` lookup) must have each
+        ``aot.resolve/compiled/warm`` lookup) must have each
         placeholder's value set derivable from a pinned bucket registry
         (a module-level int tuple) through declared *bucket functions*
         (``# graftlint: bucket-fn registry=NAME[,NAME]`` — the pass
@@ -85,7 +85,7 @@ _COMPILE_HEADS = {
     "jax.jit", "jit", "pjit", "jax.pmap", "pmap",
     "jax.shard_map", "shard_map",
 }
-_AOT_SINK_ATTRS = {"load", "resolve", "compiled", "warm"}
+_AOT_SINK_ATTRS = {"resolve", "compiled", "warm"}
 
 # the thread roles whose cones ARE the serving plane (witness detail
 # for findings; program sites in the device layer are always in scope

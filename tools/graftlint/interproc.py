@@ -533,8 +533,6 @@ def _is_device_head(head: str | None, mi: ModuleInfo,
         return False
     if head in _JIT_HEADS or head in _DEVICE_FACTORIES:
         return True
-    if head == "aot.load" or head.endswith(".aot.load"):
-        return True
     root = head.split(".")[0]
     target = mi.mod_imports.get(root)
     if target in _DEVICE_MODULES:
@@ -585,8 +583,7 @@ def _local_dataflow(fn, mi: ModuleInfo, prog: Program):
                               or head in _DEVICE_FACTORIES
                               or (root in mi.name_imports
                                   and mi.name_imports[root][1]
-                                  in _DEVICE_FACTORIES)
-                              or (head or "").endswith("aot.load"))
+                                  in _DEVICE_FACTORIES))
                 return (factoryish, not factoryish, False)
             if head and head in device_fns:
                 return (False, True, False)
