@@ -1,0 +1,79 @@
+"""Keccak-256 (the pre-NIST padding Ethereum variant) in pure Python.
+
+The reference hashes block headers / RLP payloads with go-ethereum's
+Keccak-256 (reference: crypto/hash/rlp.go) — NOT NIST SHA3-256, which
+differs only in the domain-separation padding byte (0x01 vs 0x06).
+``hashlib`` ships SHA3 only, so the permutation is implemented here; a C++
+native implementation backs this on the hot path (native/, later rounds).
+"""
+
+_ROUND_CONSTANTS = [
+    0x0000000000000001, 0x0000000000008082, 0x800000000000808A,
+    0x8000000080008000, 0x000000000000808B, 0x0000000080000001,
+    0x8000000080008081, 0x8000000000008009, 0x000000000000008A,
+    0x0000000000000088, 0x0000000080008009, 0x000000008000000A,
+    0x000000008000808B, 0x800000000000008B, 0x8000000000008089,
+    0x8000000000008003, 0x8000000000008002, 0x8000000000000080,
+    0x000000000000800A, 0x800000008000000A, 0x8000000080008081,
+    0x8000000000008080, 0x0000000080000001, 0x8000000080008008,
+]
+
+_ROTATIONS = [
+    [0, 36, 3, 41, 18],
+    [1, 44, 10, 45, 2],
+    [62, 6, 43, 15, 61],
+    [28, 55, 25, 21, 56],
+    [27, 20, 39, 8, 14],
+]
+
+_MASK = (1 << 64) - 1
+
+
+def _rotl(v, n):
+    return ((v << n) | (v >> (64 - n))) & _MASK
+
+
+def _keccak_f(state):
+    """keccak-f[1600] permutation over a 5x5 list of 64-bit lanes."""
+    for rc in _ROUND_CONSTANTS:
+        # theta
+        c = [state[x][0] ^ state[x][1] ^ state[x][2] ^ state[x][3] ^ state[x][4]
+             for x in range(5)]
+        d = [c[(x - 1) % 5] ^ _rotl(c[(x + 1) % 5], 1) for x in range(5)]
+        for x in range(5):
+            for y in range(5):
+                state[x][y] ^= d[x]
+        # rho + pi
+        b = [[0] * 5 for _ in range(5)]
+        for x in range(5):
+            for y in range(5):
+                b[y][(2 * x + 3 * y) % 5] = _rotl(state[x][y], _ROTATIONS[x][y])
+        # chi
+        for x in range(5):
+            for y in range(5):
+                state[x][y] = b[x][y] ^ ((~b[(x + 1) % 5][y]) & b[(x + 2) % 5][y])
+        # iota
+        state[0][0] ^= rc
+    return state
+
+
+def keccak256(data: bytes) -> bytes:
+    rate = 136  # 1088-bit rate for 256-bit output
+    # multi-rate padding with Keccak (pre-NIST) domain byte 0x01
+    padded = bytearray(data)
+    pad_len = rate - (len(padded) % rate)
+    if pad_len == 1:
+        padded += b"\x81"  # domain and final bit collapse into one byte
+    else:
+        padded += b"\x01" + b"\x00" * (pad_len - 2) + b"\x80"
+    state = [[0] * 5 for _ in range(5)]
+    for off in range(0, len(padded), rate):
+        block = padded[off : off + rate]
+        for i in range(rate // 8):
+            lane = int.from_bytes(block[i * 8 : i * 8 + 8], "little")
+            state[i % 5][i // 5] ^= lane
+        _keccak_f(state)
+    out = bytearray()
+    for i in range(4):  # 32 bytes = 4 lanes
+        out += state[i % 5][i // 5].to_bytes(8, "little")
+    return bytes(out)
