@@ -62,6 +62,11 @@ class EpochContext:
         return len(self.serialized)
 
 
+def _header_hash(header: Header) -> bytes:
+    with prof.stage("header_hash"):
+        return header.hash()
+
+
 class _LRU(OrderedDict):
     def __init__(self, cap: int):
         super().__init__()
@@ -162,16 +167,19 @@ class Engine:
     def decode_sig_bitmap(self, ctx: EpochContext, sig_bytes: bytes,
                           bitmap: bytes):
         """(signature point, Mask) or ValueError (sig.go:37-50)."""
-        sig = RB.sig_from_bytes(sig_bytes)
+        with prof.stage("sig_decode"):
+            sig = RB.sig_from_bytes(sig_bytes)
         if sig is None:
             raise ValueError("aggregate signature is infinity")
-        mask = Mask(ctx.points)
-        mask.set_mask(bitmap)
+        with prof.stage("mask"):
+            mask = Mask(ctx.points)
+            mask.set_mask(bitmap)
         return sig, mask
 
     def _commit_payload(self, header: Header, is_staking: bool) -> bytes:
         return construct_commit_payload(
-            header.hash(), header.block_num, header.view_id, is_staking
+            _header_hash(header), header.block_num, header.view_id,
+            is_staking,
         )
 
     def verify_header_signature(
@@ -182,7 +190,7 @@ class Engine:
         ``lane`` picks the verification scheduler's priority lane
         (default: the sync lane — replay is the engine's home turf;
         the node's live-commit path passes CONSENSUS)."""
-        cache_key = (header.hash(), sig_bytes, bitmap)
+        cache_key = (_header_hash(header), sig_bytes, bitmap)
         if cache_key in self._verified:
             return True
         ctx = self.epoch_context(header.shard_id, header.epoch)
@@ -190,7 +198,10 @@ class Engine:
             sig, mask = self.decode_sig_bitmap(ctx, sig_bytes, bitmap)
         except ValueError:
             return False
-        if not ctx.decider.is_quorum_achieved_by_mask(mask.bit_vector()):
+        with prof.stage("quorum_tally"):
+            quorum = ctx.decider.is_quorum_achieved_by_mask(
+                mask.bit_vector())
+        if not quorum:
             return False
         payload = self._commit_payload(header, is_staking)
         if self.backend is not None:
@@ -279,7 +290,7 @@ class Engine:
         host_survivors = []  # (idx, agg_pk, h_pt, sig) — host path only
         backend_calls = []  # (idx, header, ctx, payload) — sidecar path
         for idx, (header, sig_bytes, bitmap) in enumerate(items):
-            cache_key = (header.hash(), sig_bytes, bitmap)
+            cache_key = (_header_hash(header), sig_bytes, bitmap)
             if cache_key in self._verified:
                 results[idx] = True
                 continue
@@ -288,7 +299,10 @@ class Engine:
                 sig, mask = self.decode_sig_bitmap(ctx, sig_bytes, bitmap)
             except ValueError:
                 continue
-            if not ctx.decider.is_quorum_achieved_by_mask(mask.bit_vector()):
+            with prof.stage("quorum_tally"):
+                quorum = ctx.decider.is_quorum_achieved_by_mask(
+                    mask.bit_vector())
+            if not quorum:
                 continue
             payload = self._commit_payload(header, flags[idx])
             if self.backend is not None:
@@ -314,7 +328,8 @@ class Engine:
                 if RB.verify_hashed(agg_pk, h_pt, sig):
                     results[idx] = True
                     header, sig_bytes, bitmap = items[idx]
-                    self._verified.put((header.hash(), sig_bytes, bitmap))
+                    self._verified.put(
+                        (_header_hash(header), sig_bytes, bitmap))
             return results
         from .. import sched
 
@@ -330,7 +345,8 @@ class Engine:
                 if good:
                     results[idx] = True
                     header, sig_bytes, bitmap = items[idx]
-                    self._verified.put((header.hash(), sig_bytes, bitmap))
+                    self._verified.put(
+                        (_header_hash(header), sig_bytes, bitmap))
         return results
 
     def _backend_verify_batch(self, items, flags, results,
@@ -367,5 +383,6 @@ class Engine:
                 continue
             if ok:
                 results[idx] = True
-                self._verified.put((header.hash(), sig_bytes, bitmap))
+                self._verified.put(
+                    (_header_hash(header), sig_bytes, bitmap))
         return results

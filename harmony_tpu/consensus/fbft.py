@@ -23,6 +23,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from .. import bls as B
+from .. import prof
 from ..multibls import PrivateKeys
 from ..ref import bls as RB
 from .mask import Mask
@@ -282,14 +283,19 @@ class Validator(_Node):
         from .. import device as DV
 
         try:
-            mask = Mask(self.committee_points)
-            sig_bytes, bitmap = decode_sig_and_bitmap(
-                msg.payload, mask.bytes_len()
-            )
-            mask.set_mask(bitmap)
-            if not self.decider.is_quorum_achieved_by_mask(mask.bit_vector()):
+            with prof.stage("mask"):
+                mask = Mask(self.committee_points)
+                sig_bytes, bitmap = decode_sig_and_bitmap(
+                    msg.payload, mask.bytes_len()
+                )
+                mask.set_mask(bitmap)
+            with prof.stage("quorum_tally"):
+                quorum = self.decider.is_quorum_achieved_by_mask(
+                    mask.bit_vector())
+            if not quorum:
                 return False
-            sig = B.Signature.from_bytes(sig_bytes)
+            with prof.stage("sig_decode"):
+                sig = B.Signature.from_bytes(sig_bytes)
         except ValueError:
             return False
         if DV.device_enabled():

@@ -416,20 +416,21 @@ def agg_verify_hashed_on_device(table: CommitteeTable, bits, h_point,
             asarray = jnp.asarray
         fused = _fused()
         fn = _get_agg_verify_fn() if fused else OB.agg_verify
-        bm = table.pad_bits(bits)
-        hh = np.asarray(I.g2_affine_to_arr(h))
-        sg = np.asarray(I.g2_affine_to_arr(sig_point))
-        TRANSFER.inc("h2d", bm.nbytes + hh.nbytes + sg.nbytes)
-        program = f"agg_verify_b{table.size}"
-        if fused and not kernel_twin_active():
-            warm = aot.resolve(program)
-            if warm is not None:
-                fn = warm
-        first = _program_first_use(program) if fused else False
-        t0 = time.monotonic()
-        call_args = (
-            table.device_array(), asarray(bm), asarray(hh), asarray(sg)
-        )
+        with prof.stage("device_prep"):
+            bm = table.pad_bits(bits)
+            hh = np.asarray(I.g2_affine_to_arr(h))
+            sg = np.asarray(I.g2_affine_to_arr(sig_point))
+            TRANSFER.inc("h2d", bm.nbytes + hh.nbytes + sg.nbytes)
+            program = f"agg_verify_b{table.size}"
+            if fused and not kernel_twin_active():
+                warm = aot.resolve(program)
+                if warm is not None:
+                    fn = warm
+            first = _program_first_use(program) if fused else False
+            t0 = time.monotonic()
+            call_args = (
+                table.device_array(), asarray(bm), asarray(hh), asarray(sg)
+            )
         ok = fn(*call_args)
         res = np.asarray(ok)
         elapsed = time.monotonic() - t0
@@ -584,24 +585,25 @@ def agg_verify_batch_on_device(table: CommitteeTable, bits_list,
         h2d = 0
         compiles = []  # (program, first-dispatch seconds)
         for start in range(0, len(bits_list), widest):
-            chunk_bits = bits_list[start:start + widest]
-            chunk_h = h_points[start:start + widest]
-            chunk_s = sig_points[start:start + widest]
-            n, padded = len(chunk_bits), batch_bucket(len(chunk_bits))
-            sel = list(range(n)) + [0] * (padded - n)  # pad lanes sliced
-            bm = np.stack([table.pad_bits(chunk_bits[i]) for i in sel])
-            hh = np.asarray(I.g2_batch_affine([chunk_h[i] for i in sel]))
-            sg = np.asarray(I.g2_batch_affine([chunk_s[i] for i in sel]))
-            h2d += bm.nbytes + hh.nbytes + sg.nbytes
-            program = f"agg_verify_batch_b{table.size}x{padded}"
-            chunk_fn = fn
-            if fused and not kernel_twin_active():
-                warm = aot.resolve(program)
-                if warm is not None:
-                    chunk_fn = warm
-            first = _program_first_use(program) if fused else False
-            t0 = time.monotonic()
-            call_args = (tbl, asarray(bm), asarray(hh), asarray(sg))
+            with prof.stage("device_prep"):
+                chunk_bits = bits_list[start:start + widest]
+                chunk_h = h_points[start:start + widest]
+                chunk_s = sig_points[start:start + widest]
+                n, padded = len(chunk_bits), batch_bucket(len(chunk_bits))
+                sel = list(range(n)) + [0] * (padded - n)  # pad lanes sliced
+                bm = np.stack([table.pad_bits(chunk_bits[i]) for i in sel])
+                hh = np.asarray(I.g2_batch_affine([chunk_h[i] for i in sel]))
+                sg = np.asarray(I.g2_batch_affine([chunk_s[i] for i in sel]))
+                h2d += bm.nbytes + hh.nbytes + sg.nbytes
+                program = f"agg_verify_batch_b{table.size}x{padded}"
+                chunk_fn = fn
+                if fused and not kernel_twin_active():
+                    warm = aot.resolve(program)
+                    if warm is not None:
+                        chunk_fn = warm
+                first = _program_first_use(program) if fused else False
+                t0 = time.monotonic()
+                call_args = (tbl, asarray(bm), asarray(hh), asarray(sg))
             ok = chunk_fn(*call_args)
             if first:
                 compiles.append((program, time.monotonic() - t0))
@@ -676,19 +678,20 @@ def verify_on_device(pk_point, payload: bytes, sig_point) -> bool:
         fused = _fused()
         width = (_VERIFY_BUCKET
                  if fused and not kernel_twin_active() else 1)
-        pk = np.asarray(I.g1_batch_affine([pk_point] * width))
-        hh = np.asarray(I.g2_batch_affine([h] * width))
-        sg = np.asarray(I.g2_batch_affine([sig_point] * width))
-        TRANSFER.inc("h2d", pk.nbytes + hh.nbytes + sg.nbytes)
-        program = f"verify_w{width}"
-        fn = _get_verify_fn() if fused else OB.verify
-        if fused and not kernel_twin_active():
-            warm = aot.resolve(program)
-            if warm is not None:
-                fn = warm
-        first = _program_first_use(program) if fused else False
-        t0 = time.monotonic()
-        call_args = (asarray(pk), asarray(hh), asarray(sg))
+        with prof.stage("device_prep"):
+            pk = np.asarray(I.g1_batch_affine([pk_point] * width))
+            hh = np.asarray(I.g2_batch_affine([h] * width))
+            sg = np.asarray(I.g2_batch_affine([sig_point] * width))
+            TRANSFER.inc("h2d", pk.nbytes + hh.nbytes + sg.nbytes)
+            program = f"verify_w{width}"
+            fn = _get_verify_fn() if fused else OB.verify
+            if fused and not kernel_twin_active():
+                warm = aot.resolve(program)
+                if warm is not None:
+                    fn = warm
+            first = _program_first_use(program) if fused else False
+            t0 = time.monotonic()
+            call_args = (asarray(pk), asarray(hh), asarray(sg))
         ok = fn(*call_args)
         res = np.asarray(ok)
         elapsed = time.monotonic() - t0
@@ -750,37 +753,39 @@ def verify_many_on_device(pk_points, h_points, sig_points) -> list:
         h2d = 0
         compiles = []  # (program, first-dispatch seconds)
         for start in range(0, n_total, widest):
-            chunk_pk = pk_points[start:start + widest]
-            chunk_h = h_points[start:start + widest]
-            chunk_s = sig_points[start:start + widest]
-            n = len(chunk_pk)
-            padded = batch_bucket(n) if fused else n
-            pad = padded - n
-            pk = np.asarray(I.g1_batch_affine(chunk_pk))
-            hh = np.asarray(I.g2_batch_affine(chunk_h))
-            sg = np.asarray(I.g2_batch_affine(chunk_s))
-            if pad:
-                # pad with affine infinity: the twins short-circuit
-                # those lanes and the kernels' pad output is sliced off
-                pk = np.concatenate(
-                    [pk, np.zeros((pad,) + pk.shape[1:], pk.dtype)]
-                )
-                hh = np.concatenate(
-                    [hh, np.zeros((pad,) + hh.shape[1:], hh.dtype)]
-                )
-                sg = np.concatenate(
-                    [sg, np.zeros((pad,) + sg.shape[1:], sg.dtype)]
-                )
-            h2d += pk.nbytes + hh.nbytes + sg.nbytes
-            program = f"verify_w{padded}"
-            chunk_fn = fn
-            if fused and not kernel_twin_active():
-                warm = aot.resolve(program)
-                if warm is not None:
-                    chunk_fn = warm
-            first = _program_first_use(program) if fused else False
-            t0 = time.monotonic()
-            call_args = (asarray(pk), asarray(hh), asarray(sg))
+            with prof.stage("device_prep"):
+                chunk_pk = pk_points[start:start + widest]
+                chunk_h = h_points[start:start + widest]
+                chunk_s = sig_points[start:start + widest]
+                n = len(chunk_pk)
+                padded = batch_bucket(n) if fused else n
+                pad = padded - n
+                pk = np.asarray(I.g1_batch_affine(chunk_pk))
+                hh = np.asarray(I.g2_batch_affine(chunk_h))
+                sg = np.asarray(I.g2_batch_affine(chunk_s))
+                if pad:
+                    # pad with affine infinity: the twins short-circuit
+                    # those lanes and the kernels' pad output is
+                    # sliced off
+                    pk = np.concatenate(
+                        [pk, np.zeros((pad,) + pk.shape[1:], pk.dtype)]
+                    )
+                    hh = np.concatenate(
+                        [hh, np.zeros((pad,) + hh.shape[1:], hh.dtype)]
+                    )
+                    sg = np.concatenate(
+                        [sg, np.zeros((pad,) + sg.shape[1:], sg.dtype)]
+                    )
+                h2d += pk.nbytes + hh.nbytes + sg.nbytes
+                program = f"verify_w{padded}"
+                chunk_fn = fn
+                if fused and not kernel_twin_active():
+                    warm = aot.resolve(program)
+                    if warm is not None:
+                        chunk_fn = warm
+                first = _program_first_use(program) if fused else False
+                t0 = time.monotonic()
+                call_args = (asarray(pk), asarray(hh), asarray(sg))
             ok = chunk_fn(*call_args)
             if first:
                 compiles.append((program, time.monotonic() - t0))

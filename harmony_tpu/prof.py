@@ -1,27 +1,28 @@
-"""Kernel-stage profiler: where does a verification's time actually go?
+"""Stage profiler: where does a verification's host time go?
 
-PR 4 answered "where did round N spend its 800 ms?" at the span level;
-this module answers the layer below — the per-KERNEL breakdown the
-first device hour needs (docs/PERF_MODEL.md §6): which pipeline stage
-(montmul, Miller loop, final exponentiation, host hash-to-G2) costs
-what, and what does XLA itself believe about every compiled program in
-``device.py``'s jit cache (FLOPs, bytes accessed, peak temp memory,
-compile wall time).  Every prior perf claim in this repo was a model;
-these are the measurements the BENCH ledger compares against them.
+``trace.py`` answers "where did round N spend its 800 ms?" at the span
+level; this module answers the layer below: which host stage of a
+check costs what, and what does XLA itself believe about every
+compiled program in ``device.py``'s jit cache (FLOPs, bytes accessed,
+peak temp memory, compile wall time).
 
 Three surfaces:
 
-1. **Stage spans** — ``with prof.stage("hash_to_g2"):`` at the
-   host-visible stage boundaries of the pairing pipeline.  Each stage
-   records into a per-stage wall-time histogram AND opens a
-   ``prof.stage`` trace span, so stages nest under the PR-4 round
-   trace in /debug/trace.  Disabled cost is one module-bool comparison
-   (the same discipline as trace.py — this sits on the verify path).
-   The fused production program cannot be split mid-dispatch, so the
-   full four-stage breakdown comes from ``tools/bench_device.py``,
-   which runs the stages as separately-compiled programs with a device
-   sync between them; the in-process wiring covers the stages that are
-   host-visible anyway (hash-to-G2, dispatch).
+1. **Stage spans** — ``with prof.stage("hash_to_g2"):`` around one
+   host stage of the verify path.  The stages there are
+   ``header_hash`` (Keccak of a header's RLP), ``sig_decode`` (the
+   96-byte G2 decode and checks), ``mask`` (bitmap to signer vector),
+   ``quorum_tally`` (the stake-weighted 2/3 check), ``hash_to_g2`` and
+   ``device_prep`` (host prep of one device program's inputs, up to the
+   program call); they are disjoint, so their sums add up.  Each stage
+   records into a per-stage wall-time histogram, opens a
+   ``prof.stage`` trace span (so stages nest under the round trace in
+   /debug/trace) and, when jax is loaded, opens a
+   ``jax.profiler.TraceAnnotation`` named ``stage:<name>``: in any
+   ``jax.profiler`` capture the stage lands on the ``/host:`` plane, on
+   the same clock as the device ops, nested by thread.  Disabled cost
+   is one module-bool comparison (the same discipline as trace.py —
+   this sits on the verify path).
 
 2. **Program registry** — ``device.py`` reports every program shape's
    FIRST dispatch here (the one that pays the JIT compile).  The
@@ -41,21 +42,19 @@ Three surfaces:
    no second run to re-instrument).
 
 Stdlib + metrics/trace only at import; jax is touched lazily and only
-behind the armed paths.
+behind the armed paths, and a stage never imports it: a process that
+keeps jax unloaded (the twin kernels) records no annotation.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 
 from . import trace
 from .metrics import Histogram
-
-# The four pipeline stages of PERF_MODEL §1 (plus free-form extras the
-# bench tools add).  Order is the exposition order.
-STAGES = ("hash_to_g2", "montmul", "miller_loop", "final_exp")
 
 _STAGE_BUCKETS = (1e-5, 1e-4, 5e-4, 1e-3, 5e-3, 0.01, 0.05, 0.1, 0.5,
                   1.0, 5.0)
@@ -141,24 +140,33 @@ _NOOP = _NoopStage()
 
 
 class _Stage:
-    __slots__ = ("name", "_t0", "_span")
+    __slots__ = ("name", "_t0", "_span", "_ann")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self._span = trace.span("prof.stage", component="prof",
                                 stage=name, **attrs)
+        # the loaded jax only: importing it here would load jax into a
+        # process that keeps it unloaded
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self._ann = (None if profiler is None
+                     else profiler.TraceAnnotation("stage:" + name))
         self._t0 = 0.0
 
     def __enter__(self):
         self._span.__enter__()
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dt = time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         h = _labeled(
             _stage_hist, self.name, "harmony_prof_stage_seconds",
-            "wall time per pairing-pipeline stage",
+            "wall time per host stage",
             _STAGE_BUCKETS, "stage",
         )
         if h is not None:
@@ -168,9 +176,10 @@ class _Stage:
 
 
 def stage(name: str, **attrs):
-    """``with prof.stage("miller_loop"):`` — one timed pipeline stage,
-    recorded as a histogram sample and (when tracing is armed) a
-    ``prof.stage`` span nested under the caller's current span.
+    """``with prof.stage("sig_decode"):`` — one timed host stage,
+    recorded as a histogram sample, (when tracing is armed) a
+    ``prof.stage`` span nested under the caller's current span, and
+    (when jax is loaded) a ``stage:<name>`` profiler annotation.
     Disabled cost: one comparison."""
     if not _enabled:
         return _NOOP

@@ -146,6 +146,9 @@ def test_warmup_compiles_cold_programs_concurrently_then_hits(
 
     from harmony_tpu import device as DV
 
+    # the accelerator branch needs the real kernels: a test module that
+    # imports tools/obs_smoke.py sets the twin flag for its process
+    monkeypatch.delenv("HARMONY_KERNEL_TWIN", raising=False)
     monkeypatch.setattr(DV, "_fused", lambda: True)
     monkeypatch.setattr(aot, "program_spec", lambda name: (
         "tiny", (int(name.rsplit("_b", 1)[1]),),

@@ -137,3 +137,85 @@ def test_batched_replay(committee):
         [headers[0], headers[1], headers[3], headers[4]]
     )
     assert results2 == [True] * 4
+
+
+@pytest.fixture
+def twin_device(monkeypatch):
+    """The engine's device branch on the twin kernels (no XLA), with
+    the stage profiler dark until a test arms it."""
+    from harmony_tpu import device as DV
+    from harmony_tpu import prof
+
+    monkeypatch.setenv("HARMONY_KERNEL_TWIN", "1")
+    DV.use_device(True)
+    prof.reset()
+    yield DV
+    prof.reset()
+    DV.use_device(None)
+
+
+def _stage_counts():
+    from harmony_tpu import prof
+
+    return {k: v["count"] for k, v in prof.stage_summary().items()}
+
+
+def _dispatches(DV):
+    return DV.COUNTERS["batch_verify"] + DV.COUNTERS["agg_verify"]
+
+
+def test_batched_replay_records_every_stage_when_armed(committee,
+                                                        twin_device):
+    """Armed, each decoded header records sig_decode, mask and
+    quorum_tally once; each header past the tally one hash_to_g2; each
+    device program one device_prep; header_hash is taken at the cache
+    key, the commit payload and the verified-cache insert.  The
+    decisions are the same armed and dark."""
+    from harmony_tpu import prof
+
+    keys, serialized = committee
+    headers = [Header(shard_id=0, block_num=300 + n, epoch=4,
+                      view_id=300 + n) for n in range(5)]
+    items = [(h, *_sign_header(h, keys, [0, 1, 2, 3])) for h in headers]
+    items[1] = (headers[1], *_sign_header(headers[1], keys, [0, 1]))
+    items[3] = (headers[3], items[2][1], items[3][2])  # forged
+    want = [True, False, True, False, True]
+    dark = Engine(_provider(serialized), device=True)
+    assert dark.verify_headers_batch(items) == want
+    assert _stage_counts() == {}
+    prof.configure(enabled=True)
+    before = _dispatches(twin_device)
+    armed = Engine(_provider(serialized), device=True)
+    assert armed.verify_headers_batch(items) == want
+    dispatches = _dispatches(twin_device) - before
+    assert dispatches >= 1
+    assert _stage_counts() == {
+        "header_hash": 5 + 4 + 3, "sig_decode": 5, "mask": 5,
+        "quorum_tally": 5, "hash_to_g2": 4, "device_prep": dispatches,
+    }
+
+
+def test_single_seal_check_records_every_stage_when_armed(committee,
+                                                          twin_device):
+    from harmony_tpu import prof
+
+    keys, serialized = committee
+    h = Header(shard_id=0, block_num=400, epoch=4, view_id=400)
+    sig, bitmap = _sign_header(h, keys, [0, 1, 2])
+    short_sig, short_bitmap = _sign_header(h, keys, [0, 3])
+    cases = [(sig, bitmap), (short_sig, short_bitmap), (short_sig, bitmap)]
+    want = [True, False, False]
+    dark = Engine(_provider(serialized), device=True)
+    assert [dark.verify_header_signature(h, s, b) for s, b in cases] == want
+    prof.configure(enabled=True)
+    armed = Engine(_provider(serialized), device=True)
+    before = _dispatches(twin_device)
+    assert armed.verify_header_signature(h, sig, bitmap)
+    assert _dispatches(twin_device) - before == 1
+    assert _stage_counts() == {
+        "header_hash": 2, "sig_decode": 1, "mask": 1, "quorum_tally": 1,
+        "hash_to_g2": 1, "device_prep": 1,
+    }
+    armed = Engine(_provider(serialized), device=True)
+    assert [armed.verify_header_signature(h, s, b)
+            for s, b in cases] == want

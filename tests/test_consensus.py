@@ -220,3 +220,60 @@ def test_staked_quorum_exact_boundary():
     # a's power: 2/3 rounded = 0.666666666666666667 > 2/3's Dec value
     # (0.666666666666666667) -> equal, not greater
     assert not d.is_quorum_achieved(Q.Phase.COMMIT)
+
+
+# --- stages of the validator's proof check ---------------------------------
+
+
+def test_on_committed_records_every_stage_when_armed(monkeypatch):
+    """Armed, one COMMITTED proof check on the device branch (twin
+    kernels, no XLA) records each host stage once; the decisions on a
+    good, a forged and a short proof are the same armed and dark."""
+    from harmony_tpu import bls as B
+    from harmony_tpu import device as DV
+    from harmony_tpu import prof
+    from harmony_tpu.consensus import fbft as FB
+    from harmony_tpu.multibls import PrivateKeys
+
+    monkeypatch.setenv("HARMONY_KERNEL_TWIN", "1")
+    DV.use_device(True)
+    prof.reset()
+    try:
+        keys = [B.PrivateKey.generate(bytes([90 + i])) for i in range(4)]
+        serialized = [k.pub.bytes for k in keys]
+        cfg = FB.RoundConfig(committee=serialized, block_num=7, view_id=3)
+        block_hash = b"\xcd" * 32
+
+        def proof(signers, block=block_hash):
+            payload = SIG.construct_commit_payload(block, 7, 3, True)
+            agg = B.aggregate_sigs([keys[i].sign_hash(payload)
+                                    for i in signers])
+            mask = Mask([k.pub.point for k in keys])
+            for i in signers:
+                mask.set_bit(i, True)
+            return agg.bytes + mask.mask_bytes()
+
+        def check(pl):
+            v = FB.Validator(PrivateKeys.from_keys([]), cfg,
+                             Q.Decider(Q.Policy.UNIFORM, serialized))
+            return v.on_committed(FB.FBFTMessage(
+                msg_type=FB.MsgType.COMMITTED, view_id=3, block_num=7,
+                block_hash=block_hash, sender_pubkeys=[serialized[0]],
+                payload=pl))
+
+        proofs = [proof([0, 1, 2]), proof([0, 1, 2], b"\xee" * 32),
+                  proof([0, 1])]
+        dark = [check(p) for p in proofs]
+        assert dark == [True, False, False]
+        assert prof.stage_summary() == {}
+        prof.configure(enabled=True)
+        before = DV.COUNTERS["agg_verify"]
+        assert check(proofs[0])
+        assert DV.COUNTERS["agg_verify"] - before == 1
+        assert {k: v["count"] for k, v in prof.stage_summary().items()} \
+            == {"mask": 1, "quorum_tally": 1, "sig_decode": 1,
+                "hash_to_g2": 1, "device_prep": 1}
+        assert [check(p) for p in proofs] == dark
+    finally:
+        prof.reset()
+        DV.use_device(None)

@@ -75,6 +75,96 @@ def test_stage_survives_exceptions():
     assert prof.stage_summary()["final_exp"]["count"] == 1
 
 
+def _recording_annotation(events: list):
+    """A stand-in for ``jax.profiler.TraceAnnotation`` that logs its
+    construction, enter and exit."""
+
+    class Recording:
+        def __init__(self, name, **kwargs):
+            self.name = name
+            events.append(("new", name, kwargs))
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+            return False
+
+    return Recording
+
+
+def test_armed_stage_opens_one_profiler_annotation(monkeypatch):
+    import jax
+
+    events: list = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        _recording_annotation(events))
+    prof.configure(enabled=True)
+    with prof.stage("x", batch=8):
+        assert events == [("new", "stage:x", {}), ("enter", "stage:x")]
+    assert events == [("new", "stage:x", {}), ("enter", "stage:x"),
+                      ("exit", "stage:x")]
+    assert prof.stage_summary()["x"]["count"] == 1
+
+
+def test_disarmed_stage_touches_no_jax(monkeypatch):
+    class Untouchable:
+        def __getattr__(self, name):
+            raise AssertionError(f"jax.{name} touched by a dark stage")
+
+    monkeypatch.setitem(sys.modules, "jax", Untouchable())
+    assert not prof.enabled()
+    s = prof.stage("x")
+    assert s is prof._NOOP
+    with s:
+        pass
+    assert prof.stage_summary() == {}
+
+
+def test_armed_stage_without_jax_loaded_keeps_it_unloaded(monkeypatch):
+    """Twin-kernel nodes keep jax unloaded: an armed stage still
+    records its histogram sample and imports nothing."""
+    for name in [m for m in sys.modules if m == "jax"
+                 or m.startswith("jax.")]:
+        monkeypatch.delitem(sys.modules, name)
+    prof.configure(enabled=True)
+    with prof.stage("x"):
+        pass
+    assert "jax" not in sys.modules
+    assert prof.stage_summary()["x"]["count"] == 1
+
+
+def test_armed_stage_lands_on_the_host_plane_of_a_capture(tmp_path):
+    """A real CPU ``jax.profiler`` capture holds each armed stage as a
+    ``stage:<name>`` event on a ``/host:`` plane, nested by thread."""
+    import jax
+    from jax.profiler import ProfileData
+
+    prof.configure(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with prof.stage("x"):
+            with prof.stage("y"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = tmp_path.rglob("*.xplane.pb")
+    events = {
+        e.name: (e.start_ns, e.start_ns + e.duration_ns)
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith("stage:")
+    }
+    assert set(events) == {"stage:x", "stage:y"}
+    (x0, x1), (y0, y1) = events["stage:x"], events["stage:y"]
+    assert x0 <= y0 and y1 <= x1
+    assert y1 - y0 >= 2_000_000
+
+
 def test_env_var_arms_the_profiler(monkeypatch):
     """HARMONY_TPU_PROF=1 is the documented operator path; prof.py
     applies it at import and arm_from_env() re-applies after reset."""
