@@ -16,7 +16,8 @@ real kernels, and the first failure ends the run:
             committee (200 slots, bucket 256) on the sched CONSENSUS
             lane, committee table resident on the device: valid quorums
             with about a sixth of the signers dropped, one forged
-            signature, one bitmap the signature does not match;
+            signature, one bitmap the signature does not match, one
+            empty bitmap (the aggregate key at infinity);
   replay    one fused batch of 64 headers with distinct payloads
             against a 250-key committee on the sched SYNC lane, one
             header invalid;
@@ -49,7 +50,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent
 
 QUORUM_KEYS = 200      # mainnet V5 shard slots (config/sharding.py)
-QUORUM_VALID = 6       # valid quorums, plus one forged, one mismatched
+QUORUM_VALID = 6       # valid quorums, plus three to reject
 REPLAY_KEYS = 250      # the historic 4 x 250 mainnet shard committee
 REPLAY_WIDTH = 64      # one pinned replay batch bucket
 REPLAY_WINDOW_S = 2.0  # SYNC-lane flush window: all 64 headers in one
@@ -242,6 +243,10 @@ def phase_quorum(seed: int, guard: Guard, n_keys: int = QUORUM_KEYS,
     short[int(np.flatnonzero(short)[0])] = 0  # still a quorum
     cases.append(("bitmap not matching signature", short, payload, h,
                   sig, False))
+    # no signer: the masked sum is the point at infinity (Z = 0), which
+    # the kernel must reject whatever the signature
+    cases.append(("aggregate key at infinity", np.zeros_like(bits),
+                  payload, h, sig, False))
     sched.reset()
     sched.configure(enabled=True)
     lat = []
@@ -255,7 +260,8 @@ def phase_quorum(seed: int, guard: Guard, n_keys: int = QUORUM_KEYS,
         check(got == expected, f"quorum {label}: decided {got}")
     sched.reset()
     say(f"quorum {n_keys} keys (bucket {table.size}): {len(cases)} "
-        f"checks match the reference ({n_valid} accepted, 2 rejected); "
+        f"checks match the reference ({n_valid} accepted, "
+        f"{len(cases) - n_valid} rejected); "
         f"per-check s {[round(x, 4) for x in lat]}")
     guard.after("quorum", moved=("agg_verify",))
     return len(cases)

@@ -12,7 +12,9 @@ herumi call the reference makes through cgo (SURVEY.md §2.1):
 
 Conventions: secret keys are MSB-first bit tensors (B, 255); points are
 affine limb tensors in the Montgomery domain (G1 (B, 2, 32), G2
-(B, 2, 2, 32)); hashed messages arrive as twist points produced by the
+(B, 2, 2, 32)), except that a G1 point handed to the pairing is
+Jacobian (B, 3, 32), so an aggregate key goes in without an inversion;
+hashed messages arrive as twist points produced by the
 host-side map-to-field (ref/hash_to_curve.py — branchy SHA work stays on
 host per SURVEY.md §7.2).  All functions are jittable with static shapes.
 """
@@ -33,20 +35,18 @@ SK_BITS = 255  # ceil(log2 r)
 
 _H2_BITS = jnp.asarray([int(b) for b in bin(C.H2)[2:]], dtype=jnp.int32)
 
-_NEG_G1_GEN_AFF = None  # lazily built (x, -y) of the G1 generator
+_NEG_G1_GEN = None  # lazily built (x, -y, 1) of the G1 generator
 
 
-def _neg_g1_gen_aff():
-    global _NEG_G1_GEN_AFF
-    if _NEG_G1_GEN_AFF is None:
+def _neg_g1_gen():
+    global _NEG_G1_GEN
+    if _NEG_G1_GEN is None:
         # force concrete evaluation: a first call from INSIDE a trace
         # (e.g. under shard_map) must not cache a tracer into the
         # module global — that leaks into every later program
         with jax.ensure_compile_time_eval():
-            x = CV.G1_GEN[0]
-            y = fp.neg(CV.G1_GEN[1])
-            _NEG_G1_GEN_AFF = jnp.stack([x, y])
-    return _NEG_G1_GEN_AFF
+            _NEG_G1_GEN = CV.neg(CV.G1_GEN, CV.FP_OPS)
+    return _NEG_G1_GEN
 
 
 def sk_to_bits(sk_ints) -> np.ndarray:
@@ -86,12 +86,19 @@ def verify(pk_aff, h_aff, sig_aff):
     rejected (matches the reference treating identity elements as
     invalid in verification).
     """
-    neg_g1 = jnp.broadcast_to(_neg_g1_gen_aff(), pk_aff.shape)
-    ps = jnp.stack([neg_g1, pk_aff])  # (2, B, 2, 32)
+    return verify_jacobian(_affine_to_jacobian_g1(pk_aff), h_aff, sig_aff)
+
+
+def verify_jacobian(pk_jac, h_aff, sig_aff):
+    """``verify`` with pk in Jacobian coordinates (B, 3, 32), as the
+    masked G1 sum leaves it: the Miller loop takes P with any Z, so no
+    inversion runs before the pairing.  Z = 0 (infinity) is rejected."""
+    neg_g1 = jnp.broadcast_to(_neg_g1_gen(), pk_jac.shape)
+    ps = jnp.stack([neg_g1, pk_jac])  # (2, B, 3, 32)
     qs = jnp.stack([sig_aff, h_aff])  # (2, B, 2, 2, 32)
     gt = PR.pairing_product(ps, qs)
     ok = PR.is_one(gt)
-    pk_finite = ~fp.is_zero(pk_aff[..., 1, :])
+    pk_finite = ~fp.is_zero(pk_jac[..., 2, :])
     sig_finite = ~T.fp2_is_zero(sig_aff[..., 1, :, :])
     return ok & pk_finite & sig_finite
 
@@ -106,13 +113,13 @@ def agg_verify(pk_affs, bitmap, h_aff, agg_sig_aff):
 
     pk_affs: (N, 2, 32) committee pubkeys (affine), bitmap: (N,),
     h_aff / agg_sig_aff: single affine points (2, 2, 32).
-    Returns a scalar bool.
+    Returns a scalar bool.  The Jacobian aggregate key goes to the
+    pairing as it is; one at infinity (no signer, or keys that cancel)
+    is rejected.
     """
     jac = _affine_to_jacobian_g1(pk_affs)
-    agg_pk = CV.masked_sum(jac, bitmap, CV.FP_OPS)
-    ax, ay = CV.to_affine(agg_pk, CV.FP_OPS)
-    pk_aff = jnp.stack([ax, ay])[None]  # (1, 2, 32)
-    return verify(pk_aff, h_aff[None], agg_sig_aff[None])[0]
+    agg_pk = CV.masked_sum(jac, bitmap, CV.FP_OPS)  # (3, 32)
+    return verify_jacobian(agg_pk[None], h_aff[None], agg_sig_aff[None])[0]
 
 
 def agg_verify_batch(pk_affs, bitmaps, h_affs, agg_sig_affs):
@@ -130,9 +137,7 @@ def agg_verify_batch(pk_affs, bitmaps, h_affs, agg_sig_affs):
     """
     jac = _affine_to_jacobian_g1(pk_affs)  # (N, 3, 32)
     agg = jax.vmap(lambda bm: CV.masked_sum(jac, bm, CV.FP_OPS))(bitmaps)
-    ax, ay = CV.to_affine(agg, CV.FP_OPS)  # (B, 32) each
-    pk_aff = jnp.stack([ax, ay], axis=-2)  # (B, 2, 32)
-    return verify(pk_aff, h_affs, agg_sig_affs)
+    return verify_jacobian(agg, h_affs, agg_sig_affs)  # agg (B, 3, 32)
 
 
 def aggregate_sigs(sig_affs, bitmap=None):

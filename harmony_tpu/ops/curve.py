@@ -32,11 +32,11 @@ from . import towers as T
 class FieldOps:
     """Vectorized field-op table the generic group law is written against."""
 
-    def __init__(self, *, mul, sqr, add, sub, neg, inv, is_zero, select,
+    def __init__(self, *, mul, sqr, add, sub, neg, is_zero, select,
                  one, zero, coord_axes):
         self.mul, self.sqr = mul, sqr
         self.add, self.sub, self.neg = add, sub, neg
-        self.inv, self.is_zero, self.select = inv, is_zero, select
+        self.is_zero, self.select = is_zero, select
         self.one, self.zero = one, zero
         # number of trailing axes of one field element (1 for Fp, 2 for Fp2)
         self.coord_axes = coord_axes
@@ -54,7 +54,6 @@ FP_OPS = FieldOps(
     add=fp.add,
     sub=fp.sub,
     neg=fp.neg,
-    inv=fp.inv,
     is_zero=fp.is_zero,
     select=fp.select,
     one=lambda shape=(): jnp.broadcast_to(fp.ONE_MONT, (*shape, fp.N_LIMBS)),
@@ -68,7 +67,6 @@ FP2_OPS = FieldOps(
     add=T.fp2_add,
     sub=T.fp2_sub,
     neg=T.fp2_neg,
-    inv=T.fp2_inv,
     is_zero=T.fp2_is_zero,
     select=T.fp2_select,
     one=T.fp2_one,
@@ -214,21 +212,6 @@ def scalar_mul(pt, bits, ops):
     acc0 = infinity(ops, _batch_shape(pt, ops))
     acc, _ = jax.lax.scan(step, acc0, xs)
     return acc
-
-
-# graftlint: kernel bounds=(limb, fieldops) -> (limb, limb); domain=(mont, any) -> (mont, mont)
-def to_affine(pt, ops):
-    """Jacobian -> affine (x, y); infinity maps to (0, 0)."""
-    x, y, z = _coords(pt, ops)
-    inf = ops.is_zero(z)
-    zi = ops.inv(z)
-    zi2 = ops.sqr(zi)
-    m = ops.mul(ops.stack([x, ops.mul(y, zi)]), ops.stack([zi2, zi2]))
-    ax, ay = m[0], m[1]
-    zero = jnp.zeros_like(ax)
-    ax = jnp.where(inf[(...,) + (None,) * ops.coord_axes], zero, ax)
-    ay = jnp.where(inf[(...,) + (None,) * ops.coord_axes], zero, ay)
-    return ax, ay
 
 
 # graftlint: kernel bounds=(limb, any, fieldops) -> limb; domain=(mont, any, any) -> mont

@@ -9,16 +9,16 @@ Algorithm (bit-for-bit the bigint twin in ref/pairing.py
 miller_loop_projective, which the tests pin against the affine ground
 truth):
 
-- Miller loop over the 63 bits of |x| as ONE lax.scan with a uniform body
-  (double-step always; add-step computed and select-masked by the bit) —
-  a single compiled body instead of 63 unrolled variants.
+- Miller loop over |x|'s static square-and-multiply schedule: one
+  outer lax.scan over its 6 segments, each a fori_loop of double-steps
+  plus one masked add-step (see miller_loop).
 - Twist-Jacobian line construction with denominator elimination; lines
-  live in the sparse Fp12 basis {v^2, w, w v}.
+  live in the sparse Fp12 basis {v^2, w, w v}.  P itself is Jacobian:
+  every line is scaled by Z_P^3, which lies in Fp* and which the final
+  exponentiation sends to 1, so no inversion precedes the loop.
 - Final exponentiation: easy part via conjugate / inverse / Frobenius^2;
-  hard part is a fixed-exponent square-and-multiply over the 1509 bits of
-  (p^4 - p^2 + 1)/r.  (The x-addition-chain + cyclotomic-squaring upgrade
-  is a planned optimization; this version optimizes for a small compiled
-  graph.)
+  hard part by the x-addition chain with cyclotomic squarings (see
+  final_exponentiation).
 
 Batching: points are batched over leading axes; products of pairings
 (the aggregate-verify shape) share one final exponentiation.
@@ -97,8 +97,9 @@ def _sparse_line_to_fp12(c_v2, c_w, c_wv):
     return jnp.stack([c0, c1], axis=-4)
 
 
-def _dbl_step(x, y, z, xp3, yp2):
-    """Twist-Jacobian doubling + tangent line at P (precomputed 3xp, 2yp)."""
+def _dbl_step(x, y, z, p_lin):
+    """Twist-Jacobian doubling + tangent line at P, the line scaled by
+    Z_P^3 (p_lin = stacked (Y_P, 3 X_P Z_P, Z_P^3), see miller_loop)."""
     sq = T.fp2_sqr(jnp.stack([x, y, z]))
     xsq, ysq, zsq = sq[0], sq[1], sq[2]
     m = T.fp2_mul(jnp.stack([zsq, xsq]), jnp.stack([z, x]))
@@ -107,9 +108,13 @@ def _dbl_step(x, y, z, xp3, yp2):
         jnp.stack([T.fp2_add(y, y), xsq]),
         jnp.stack([z3p, zsq]),
     )
-    c_v2 = _fp2_scale_fp(m[0], yp2)  # 2 Y Z^3 * yp  (yp2 = yp, x2 folded)
-    c_wv = fp.neg(_fp2_scale_fp(m[1], xp3))  # -3 X^2 Z^2 * xp
-    c_w = fp.sub(_small(x3p, 3), _small(ysq, 2))  # 3 X^3 - 2 Y^2
+    lin = _fp2_scale_fp(
+        jnp.stack([m[0], m[1], fp.sub(_small(x3p, 3), _small(ysq, 2))]),
+        p_lin,
+    )
+    c_v2 = lin[0]  # 2 Y Z^3 * Y_P
+    c_wv = fp.neg(lin[1])  # -3 X^2 Z^2 * X_P Z_P
+    c_w = lin[2]  # (3 X^3 - 2 Y^2) * Z_P^3
     # dbl-2009-l
     b = ysq
     csq = T.fp2_sqr(jnp.stack([b, T.fp2_add(x, b)]))
@@ -124,20 +129,22 @@ def _dbl_step(x, y, z, xp3, yp2):
     return (x3, y3, z3), (c_v2, c_w, c_wv)
 
 
-def _add_step(x, y, z, xq, yq, xp_m, yp_m):
-    """Twist-Jacobian mixed addition of the affine base Q + chord line."""
+def _add_step(x, y, z, xq, yq, q_z3, p_lin):
+    """Twist-Jacobian mixed addition of the affine base Q + chord line,
+    the line scaled by Z_P^3 (q_z3 = stacked (xq, yq) Z_P^3, p_lin =
+    stacked (Y_P, X_P Z_P))."""
     zsq = T.fp2_sqr(z)
     z3p = T.fp2_mul(zsq, z)
     m = T.fp2_mul(jnp.stack([yq, xq]), jnp.stack([z3p, zsq]))
     s2, u2 = m[0], m[1]
-    num = fp.sub(y, s2)  # (Y - yq Z^3), negated slope numerator sense below
-    # NOTE: ref uses num = Y - yq*Z^3 with line anchored at Q
+    num = fp.sub(y, s2)  # Y - yq Z^3
     h = fp.sub(u2, x)
     den = T.fp2_mul(z, fp.neg(h))  # Z (X - xq Z^2) = -Z*H
-    c_v2 = _fp2_scale_fp(den, yp_m)
-    c_wv = fp.neg(_fp2_scale_fp(num, xp_m))
-    m = T.fp2_mul(jnp.stack([xq, yq]), jnp.stack([num, den]))
-    c_w = fp.sub(m[0], m[1])
+    lin = _fp2_scale_fp(jnp.stack([den, num]), p_lin)
+    c_v2 = lin[0]  # den * Y_P
+    c_wv = fp.neg(lin[1])  # -num * X_P Z_P
+    m = T.fp2_mul(q_z3, jnp.stack([num, den]))
+    c_w = fp.sub(m[0], m[1])  # (xq num - yq den) * Z_P^3
     # madd-2007-bl (Z2 = 1)
     r = _small(fp.sub(s2, y), 2)
     sq = T.fp2_sqr(jnp.stack([_small(h, 2), r, T.fp2_add(z, h)]))
@@ -152,26 +159,38 @@ def _add_step(x, y, z, xq, yq, xp_m, yp_m):
 
 
 # graftlint: kernel bounds=(limb, limb) -> limb; domain=(mont, mont) -> mont
-def miller_loop(p_aff, q_aff):
-    """f_{|x|,Q}(P), conjugated for x < 0.  Finite affine inputs only:
-    p_aff (..., 2, 32) over Fp, q_aff (..., 2, 2, 32) over Fp2.
+def miller_loop(p_jac, q_aff):
+    """f_{|x|,Q}(P), conjugated for x < 0, up to a factor in Fp* that
+    the final exponentiation sends to 1.  p_jac (..., 3, 32): P in
+    Jacobian coordinates (X, Y, Z) over Fp, Z != 0 (affine callers pass
+    Z = 1); q_aff (..., 2, 2, 32): finite affine Q over Fp2.
+
+    P enters the lines only through xp = X/Z^2 and yp = Y/Z^3, so every
+    line is scaled by Z^3: its coefficients take Y, X Z and Z^3 where
+    they took yp, xp and 1 — no inversion of Z.
 
     The loop follows |x|'s STATIC bit schedule (_schedule): an outer
     scan over the 6 segments; each runs its double-steps in a dynamic-
     length fori_loop and applies one masked add-step.  The uniform
     per-bit variant paid a full add-step + dense Fp12 multiply on all
     63 iterations for the 5 that use them."""
-    xp = p_aff[..., 0, :]
-    yp = p_aff[..., 1, :]
+    xp = p_jac[..., 0, :]
+    yp = p_jac[..., 1, :]
+    zp = p_jac[..., 2, :]
     xq = q_aff[..., 0, :, :]
     yq = q_aff[..., 1, :, :]
-    xp3 = _small(xp, 3)
+    m = fp.mont_mul(jnp.stack([xp, zp]), zp)
+    xz, zz = m[0], m[1]  # X Z, Z^2
+    z3 = fp.mont_mul(zz, zp)
+    q_z3 = _fp2_scale_fp(jnp.stack([xq, yq]), z3)  # (xq, yq) Z^3
+    dbl_lin = jnp.stack([yp, _small(xz, 3), z3])
+    add_lin = jnp.stack([yp, xz])
     batch = xp.shape[:-1]
     one2 = T.fp2_one(batch)
 
     def dbl_once(_, carry):
         f, x, y, z = carry
-        (x, y, z), (c_v2, c_w, c_wv) = _dbl_step(x, y, z, xp3, yp)
+        (x, y, z), (c_v2, c_w, c_wv) = _dbl_step(x, y, z, dbl_lin)
         f = T.fp12_mul(T.fp12_sqr(f), _sparse_line_to_fp12(c_v2, c_w, c_wv))
         return (f, x, y, z)
 
@@ -179,7 +198,9 @@ def miller_loop(p_aff, q_aff):
         n, do_add = seg
         carry = jax.lax.fori_loop(0, n, dbl_once, carry)
         f, x, y, z = carry
-        (xa, ya, za), (a_v2, a_w, a_wv) = _add_step(x, y, z, xq, yq, xp, yp)
+        (xa, ya, za), (a_v2, a_w, a_wv) = _add_step(
+            x, y, z, xq, yq, q_z3, add_lin
+        )
         fa = T.fp12_mul(f, _sparse_line_to_fp12(a_v2, a_w, a_wv))
         take = do_add == 1
         f = jnp.where(take, fa, f)
@@ -245,18 +266,18 @@ def final_exponentiation(f):
 
 
 # graftlint: kernel bounds=(limb, limb) -> limb; domain=(mont, mont) -> mont
-def pairing(p_aff, q_aff):
-    """Batched full pairing e(P, Q)."""
-    return final_exponentiation(miller_loop(p_aff, q_aff))
+def pairing(p_jac, q_aff):
+    """Batched full pairing e(P, Q); P Jacobian, Q affine (miller_loop)."""
+    return final_exponentiation(miller_loop(p_jac, q_aff))
 
 
 # graftlint: kernel bounds=(limb, limb) -> limb; domain=(mont, mont) -> mont
-def pairing_product(p_aff, q_aff):
+def pairing_product(p_jac, q_aff):
     """prod_k e(P_k, Q_k) over the FIRST axis, one shared final
     exponentiation — the aggregate-verify shape (reference:
     internal/chain/engine.go:619-642 does exactly two such pairings per
-    block; batch replay does many)."""
-    fs = miller_loop(p_aff, q_aff)  # (K, ..., fp12)
+    block; batch replay does many).  P Jacobian, Q affine."""
+    fs = miller_loop(p_jac, q_aff)  # (K, ..., fp12)
     return final_exponentiation(fp12_tree_reduce(fs))
 
 

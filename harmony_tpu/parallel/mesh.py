@@ -79,7 +79,7 @@ def sharded_masked_sum(mesh: Mesh):
 def sharded_pairing_product(mesh: Mesh):
     """prod_k e(P_k, Q_k) with the pair axis sharded: local Miller loops
     and local Fp12 products per device, one all_gather, then a replicated
-    merge + final exponentiation."""
+    merge + final exponentiation.  P Jacobian (K, 3, 32), Q affine."""
 
     @partial(
         jax.shard_map,
@@ -107,8 +107,7 @@ def sharded_agg_verify(mesh: Mesh):
     @jax.jit
     def fn(pk_jac, bitmap, h_aff, agg_sig_aff):
         agg = masked(pk_jac, bitmap)
-        ax, ay = CV.to_affine(agg, CV.FP_OPS)
-        pk_aff = jnp.stack([ax, ay])[None]
-        return OB.verify(pk_aff, h_aff[None], agg_sig_aff[None])[0]
+        return OB.verify_jacobian(agg[None], h_aff[None],
+                                  agg_sig_aff[None])[0]
 
     return fn

@@ -103,6 +103,11 @@ def pairing(p_pt, q_pt):
 #   add:  c_v2 = yp Z (X - xq Z^2),  c_wv = -xp (Y - yq Z^3),
 #         c_w = xq (Y - yq Z^3) - yq Z (X - xq Z^2)
 #
+# P may be Jacobian too, P = (X_P, Y_P, Z_P) with xp = X_P/Z_P^2 and
+# yp = Y_P/Z_P^3: every line is then scaled by Z_P^3 (in Fp*, so the
+# final exponentiation's p^6 - 1 factor sends it to 1), and Y_P, X_P Z_P
+# and Z_P^3 stand where yp, xp and 1 stood — no inversion of Z_P.
+#
 # This bigint twin exists so the TPU implementation can be debugged
 # step-by-step against exact integers; test_ref_pairing_bls.py checks it
 # agrees with the affine miller_loop after final exponentiation.
@@ -115,12 +120,18 @@ def _sparse_line_to_fp12(c_v2, c_w, c_wv):
     return (c0, c1)
 
 
-def miller_loop_projective(p_pt, q_pt):
+def miller_loop_projective(p_pt, q_pt, zp=1):
     """f_{|x|,Q}(P) with twist-Jacobian steps; equals miller_loop up to
-    subfield factors (identical pairing after final exponentiation)."""
+    subfield factors (identical pairing after final exponentiation).
+
+    ``p_pt`` = (X_P, Y_P) with Z coordinate ``zp`` (nonzero): the
+    Jacobian point (X_P/zp^2, Y_P/zp^3), every line scaled by zp^3 as
+    the TPU kernel scales it.  zp = 1 is the affine P."""
     if p_pt is None or q_pt is None:
         return F.FP12_ONE
-    xp, yp = p_pt
+    yp = p_pt[1]
+    xp = p_pt[0] * zp % P  # X_P Z_P
+    zp3 = pow(zp, 3, P)
     xq, yq = q_pt
     x, y, z = xq, yq, F.FP2_ONE  # Jacobian T = Q
 
@@ -131,8 +142,11 @@ def miller_loop_projective(p_pt, q_pt):
         xsq = F.fp2_sqr(x)
         ysq = F.fp2_sqr(y)
         c_v2 = F.fp2_scalar(F.fp2_mul(y, z3), 2 * yp % P)
-        c_w = F.fp2_sub(
-            F.fp2_scalar(F.fp2_mul(xsq, x), 3), F.fp2_scalar(ysq, 2)
+        c_w = F.fp2_scalar(
+            F.fp2_sub(
+                F.fp2_scalar(F.fp2_mul(xsq, x), 3), F.fp2_scalar(ysq, 2)
+            ),
+            zp3,
         )
         c_wv = F.fp2_neg(F.fp2_scalar(F.fp2_mul(xsq, zsq), 3 * xp % P))
         # dbl-2009-l
@@ -156,7 +170,9 @@ def miller_loop_projective(p_pt, q_pt):
         den = F.fp2_mul(z, F.fp2_sub(x, F.fp2_mul(xq, zsq)))  # Z(X - xq Z^2)
         c_v2 = F.fp2_scalar(den, yp)
         c_wv = F.fp2_neg(F.fp2_scalar(num, xp))
-        c_w = F.fp2_sub(F.fp2_mul(xq, num), F.fp2_mul(yq, den))
+        c_w = F.fp2_scalar(
+            F.fp2_sub(F.fp2_mul(xq, num), F.fp2_mul(yq, den)), zp3
+        )
         # Jacobian + affine (add-2007-bl with Z2 = 1)
         u2 = F.fp2_mul(xq, zsq)
         s2 = F.fp2_mul(yq, z3)

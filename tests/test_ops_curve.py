@@ -93,11 +93,3 @@ def test_masked_sum_duplicate_points():
     out = CV.masked_sum(dup, jnp.asarray([1, 1]), CV.FP_OPS)
     assert I.arr_to_g1_affine(np.array(out)) == RC.g1.dbl(G1_REF[0])
 
-
-def test_to_affine_roundtrip():
-    ax, ay = CV.to_affine(G1_PTS, CV.FP_OPS)
-    for i in range(4):
-        assert (
-            I.arr_to_fp(np.array(ax[i])),
-            I.arr_to_fp(np.array(ay[i])),
-        ) == G1_REF[i]

@@ -24,6 +24,13 @@ def _g1_aff(p):
     return np.stack([I.fp_to_arr(p[0]), I.fp_to_arr(p[1])])
 
 
+def _g1_jac(p, zp=1):
+    """The affine point p as a (3, 32) Jacobian tensor with Z = zp."""
+    x = p[0] * zp * zp % RP.P
+    y = p[1] * pow(zp, 3, RP.P) % RP.P
+    return np.stack([I.fp_to_arr(x), I.fp_to_arr(y), I.fp_to_arr(zp)])
+
+
 def _g2_aff(q):
     return np.stack([I.fp2_to_arr(q[0]), I.fp2_to_arr(q[1])])
 
@@ -44,7 +51,7 @@ def h_point():
 def test_miller_loop_matches_bigint_twin():
     ps = [G1_GEN, g1.mul(G1_GEN, 123456789)]
     qs = [G2_GEN, g2.mul(G2_GEN, 987654321)]
-    p_arr = jnp.asarray(np.stack([_g1_aff(p) for p in ps]))
+    p_arr = jnp.asarray(np.stack([_g1_jac(p) for p in ps]))
     q_arr = jnp.asarray(np.stack([_g2_aff(q) for q in qs]))
     f = OP.miller_loop(p_arr, q_arr)
     for i in range(2):
@@ -53,10 +60,27 @@ def test_miller_loop_matches_bigint_twin():
         )
 
 
+def test_miller_loop_jacobian_p_matches_bigint_twin():
+    # P with Z != 1, as the masked G1 sum hands it to the pairing: the
+    # kernel's Z^3-scaled lines equal the twin's bit for bit
+    ps = [G1_GEN, g1.mul(G1_GEN, 123456789)]
+    qs = [G2_GEN, g2.mul(G2_GEN, 987654321)]
+    zs = [0x1F2E3D4C5B6A7988, RP.P - 3]
+    p_arr = jnp.asarray(np.stack([_g1_jac(p, z) for p, z in zip(ps, zs)]))
+    q_arr = jnp.asarray(np.stack([_g2_aff(q) for q in qs]))
+    f = OP.miller_loop(p_arr, q_arr)
+    for i in range(2):
+        assert I.arr_to_fp12(np.array(f[i])) == RP.miller_loop_projective(
+            (ps[i][0] * zs[i] ** 2 % RP.P, ps[i][1] * zs[i] ** 3 % RP.P),
+            qs[i],
+            zs[i],
+        )
+
+
 def test_pairing_matches_reference_gt():
     ps = [G1_GEN, g1.mul(G1_GEN, 123456789)]
     qs = [G2_GEN, g2.mul(G2_GEN, 987654321)]
-    p_arr = jnp.asarray(np.stack([_g1_aff(p) for p in ps]))
+    p_arr = jnp.asarray(np.stack([_g1_jac(p) for p in ps]))
     q_arr = jnp.asarray(np.stack([_g2_aff(q) for q in qs]))
     e = OP.pairing(p_arr, q_arr)
     for i in range(2):
@@ -67,7 +91,7 @@ def test_pairing_product_cancellation():
     # e(-G1, 2 G2) * e(2 G1, G2) == 1
     pp = [g1.neg(G1_GEN), g1.dbl(G1_GEN)]
     qq = [g2.dbl(G2_GEN), G2_GEN]
-    p_arr = jnp.asarray(np.stack([_g1_aff(p) for p in pp]))
+    p_arr = jnp.asarray(np.stack([_g1_jac(p) for p in pp]))
     q_arr = jnp.asarray(np.stack([_g2_aff(q) for q in qq]))
     assert bool(OP.is_one(OP.pairing_product(p_arr, q_arr)))
 
@@ -91,6 +115,17 @@ def test_bls_agg_verify_bitmap(keys, h_point):
     ag = jnp.asarray(_g2_aff(agg))
     assert bool(OB.agg_verify(pk, jnp.asarray([1, 0, 1, 1]), h_arr, ag))
     assert not bool(OB.agg_verify(pk, jnp.asarray([1, 1, 1, 1]), h_arr, ag))
+
+
+def test_bls_agg_verify_rejects_aggregate_key_at_infinity(keys, h_point):
+    # a key and its negation sum to infinity (Z = 0): no signature,
+    # however formed, passes for it
+    _, pks, sigs = keys
+    pk = jnp.asarray(np.stack([_g1_aff(p) for p in
+                               [pks[0], g1.neg(pks[0]), pks[1], pks[2]]]))
+    h_arr = jnp.asarray(_g2_aff(h_point))
+    ag = jnp.asarray(_g2_aff(sigs[0]))
+    assert not bool(OB.agg_verify(pk, jnp.asarray([1, 1, 0, 0]), h_arr, ag))
 
 
 def test_device_sign_matches_reference(keys, h_point):

@@ -61,15 +61,15 @@ def base_points():
 def test_dbl_step_point_half_matches_group_law(base_points):
     t, _ = base_points
     x, y, z = _g2_jac_from_affine(t)
-    xp3 = fp.to_mont(np.zeros(32, dtype=np.int32))  # line inputs: any
-    yp2 = xp3  # valid Fp residues; the point half ignores them
+    # line inputs: any valid Fp residues; the point half ignores them
+    p_lin = np.stack([fp.to_mont(np.zeros(32, dtype=np.int32))] * 3)
 
     @jax.jit
-    def step(x, y, z, a, b):
-        (x3, y3, z3), _ = OP._dbl_step(x, y, z, a, b)
+    def step(x, y, z, p_lin):
+        (x3, y3, z3), _ = OP._dbl_step(x, y, z, p_lin)
         return x3, y3, z3
 
-    x3, y3, z3 = step(x, y, z, xp3, yp2)
+    x3, y3, z3 = step(x, y, z, p_lin)
     assert _g2_affine_from_jac(x3, y3, z3) == g2.dbl(t)
 
 
@@ -81,11 +81,12 @@ def test_add_step_point_half_matches_group_law(base_points):
     dummy = fp.to_mont(np.zeros(32, dtype=np.int32))
 
     @jax.jit
-    def step(x, y, z, qx, qy, a, b):
-        (x3, y3, z3), _ = OP._add_step(x, y, z, qx, qy, a, b)
+    def step(x, y, z, qx, qy, q_z3, p_lin):
+        (x3, y3, z3), _ = OP._add_step(x, y, z, qx, qy, q_z3, p_lin)
         return x3, y3, z3
 
-    x3, y3, z3 = step(x, y, z, qx, qy, dummy, dummy)
+    x3, y3, z3 = step(x, y, z, qx, qy, np.stack([qx, qy]),
+                      np.stack([dummy] * 2))
     assert _g2_affine_from_jac(x3, y3, z3) == g2.add(t, q)
 
 
@@ -98,11 +99,11 @@ def test_dbl_chain_stays_on_curve_and_consistent(base_points):
     dummy = fp.to_mont(np.zeros(32, dtype=np.int32))
 
     @jax.jit
-    def chain(x, y, z, a, b):
+    def chain(x, y, z, p_lin):
         for _ in range(3):
-            (x, y, z), _ = OP._dbl_step(x, y, z, a, b)
+            (x, y, z), _ = OP._dbl_step(x, y, z, p_lin)
         return x, y, z
 
-    x3, y3, z3 = chain(x, y, z, dummy, dummy)
+    x3, y3, z3 = chain(x, y, z, np.stack([dummy] * 3))
     want = g2.dbl(g2.dbl(g2.dbl(t)))
     assert _g2_affine_from_jac(x3, y3, z3) == want

@@ -31,7 +31,7 @@ def test_pairing_gt_identical_across_backends():
     from harmony_tpu.ops import pairing as OP
     from harmony_tpu.ref.curve import G1_GEN, G2_GEN, g1, g2
 
-    ps = I.g1_batch_affine([G1_GEN, g1.dbl(G1_GEN)])
+    ps = I.batch(I.g1_affine_to_jacobian_arr, [G1_GEN, g1.dbl(G1_GEN)])
     qs = I.g2_batch_affine([G2_GEN, g2.dbl(G2_GEN)])
 
     fp.set_backend("scan")

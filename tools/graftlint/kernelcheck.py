@@ -502,7 +502,6 @@ _FIELD_METHODS = {
     "sub": ([_LIMB_SPEC, _LIMB_SPEC], "same", _LIMB_SPEC, "same"),
     "neg": ([_LIMB_SPEC], "same", _LIMB_SPEC, "same"),
     "dbl_": ([_LIMB_SPEC], "same", _LIMB_SPEC, "same"),
-    "inv": ([_LIMB_SPEC], "same", _LIMB_SPEC, "same"),
     "is_zero": ([_ANY_SPEC], None, _BIT_SPEC, DOM_NEUTRAL),
     "select": ([_ANY_SPEC, _LIMB_SPEC, _LIMB_SPEC], "sel",
                _LIMB_SPEC, "same"),

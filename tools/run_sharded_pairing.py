@@ -66,7 +66,7 @@ def main() -> int:
     # known answer (twin-checked below)
     p_pts = [g1.mul(G1_GEN, 3), g1.neg(G1_GEN)]
     q_pts = [G2_GEN, g2.mul(G2_GEN, 3)]
-    p_arr = jnp.asarray(I.g1_batch_affine(p_pts))
+    p_arr = jnp.asarray(I.batch(I.g1_affine_to_jacobian_arr, p_pts))
     q_arr = jnp.asarray(I.g2_batch_affine(q_pts))
 
     t0 = time.monotonic()
