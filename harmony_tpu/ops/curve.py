@@ -12,7 +12,7 @@ Design:
   special cases (either operand at infinity, P + P, P + (-P)) are fixed up
   with vectorized selects, so one fused program serves the whole batch.
 - a = 0 short-Weierstrass formulas (dbl-2009-l / add-2007-bl structure),
-  with independent products stacked into shared mont_mul scans (4 stacked
+  with independent products stacked into shared mont_mul calls (4 stacked
   calls per double, 6 per add instead of 7/16 sequential muls).
 - Generic over the coordinate field via a small op table; G1 and G2 share
   all the code.

@@ -1,9 +1,8 @@
 """Pallas TPU kernel for batched Montgomery multiplication.
 
-The scan-based mont_mul in ops/fp.py round-trips its accumulator through
-HBM on every of the 32 CIOS steps; this kernel keeps the whole
-accumulator in VMEM/registers and unrolls the loop, so HBM traffic drops
-to reading A, B and writing the result once per tile.
+This kernel runs CIOS with the whole accumulator in VMEM/registers and
+the loop unrolled, so HBM traffic is reading A, B and writing the
+result once per tile.
 
 Layout: limbs live on the SUBLANE axis, batch on the LANE axis —
 a (32, 128) int32 tile is exactly one VPU-shaped block (32 sublanes x
@@ -11,8 +10,8 @@ a (32, 128) int32 tile is exactly one VPU-shaped block (32 sublanes x
 the full tile.  The public wrapper transposes from the framework's
 (..., 32) limbs-last convention at the boundary.
 
-Used on real TPUs; interpret mode covers CPU tests.  The jnp scan path
-remains the fallback (ops/fp.py mont_mul).
+Used on real TPUs; interpret mode covers CPU tests.  The jnp path
+(ops/fp.py mont_mul) is the default backend.
 """
 
 import functools

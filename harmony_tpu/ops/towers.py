@@ -10,7 +10,7 @@ TPU-shaping trick: every mul at every level is Karatsuba with *independent*
 sub-products, and each level is written shape-polymorphically, so the
 sub-products stack onto a new leading axis.  A single Fp12 multiplication
 therefore reaches ops/fp.py as ONE mont_mul call on a (3, 6, 3, ..., 32)
-stack — 54 Fp products in one fused scan, keeping the VPU wide instead of
+stack — 54 Fp products in one mont_mul call, keeping the VPU wide instead of
 dispatching 54 tiny kernels.
 
 Montgomery domain throughout.
@@ -138,7 +138,7 @@ def fp6_neg(a):
 
 # graftlint: kernel bounds=(limb, limb) -> limb; domain=(mont, mont) -> mont
 def fp6_mul(a, b):
-    """Karatsuba-3: 6 stacked Fp2 muls (18 Fp muls in one scan)."""
+    """Karatsuba-3: 6 stacked Fp2 muls (18 Fp muls in one mont_mul)."""
     a, b = jnp.broadcast_arrays(a, b)
     a0, a1, a2 = _split3(a)
     b0, b1, b2 = _split3(b)
@@ -210,7 +210,7 @@ def fp12_sub(a, b):
 
 # graftlint: kernel bounds=(limb, limb) -> limb; domain=(mont, mont) -> mont
 def fp12_mul(a, b):
-    """Karatsuba-2 over Fp6: 3 stacked Fp6 muls = one 54-product scan."""
+    """Karatsuba-2 over Fp6: 3 stacked Fp6 muls = one 54-product mont_mul."""
     a, b = jnp.broadcast_arrays(a, b)
     a0, a1 = _split12(a)
     b0, b1 = _split12(b)
@@ -231,7 +231,7 @@ def fp12_sqr(a):
         c0 = (a0 + a1)(a0 + v a1) - v0 - v v0
         c1 = 2 v0
 
-    Both products are independent and stack into one 36-Fp-product scan.
+    Both products are independent and stack into one 36-Fp-product mont_mul.
     """
     a0, a1 = _split12(a)
     va1 = fp6_mul_v(a1)

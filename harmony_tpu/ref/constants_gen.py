@@ -62,6 +62,8 @@ def generate() -> str:
     w(f"H2 = {H2}\n")
     w(f"P_INV_NEG = {p_inv_neg}\n\n")
     w(f"P_LIMBS = {_limbs(P)}\n\n")
+    w("# -p^-1 mod R: the factor of the one-shot Montgomery reduction\n")
+    w(f"P_INV_NEG_LIMBS = {_limbs(-pow(P, -1, R_MONT) % R_MONT)}\n\n")
     w(f"# Montgomery form of 1 (= R mod p) and the conversion factor R^2 mod p\n")
     w(f"ONE_MONT = {_limbs(_to_mont(1))}\n\n")
     w(f"R2_LIMBS = {_limbs(r2)}\n\n")

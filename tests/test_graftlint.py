@@ -479,6 +479,28 @@ def test_gl09_bound_is_dtype_parameterized():
     assert "[-128, 127]" in gl09[0].message
 
 
+def test_gl09_sum_over_a_stack_counts_its_terms():
+    """A sum over axis 0 of a jnp.stack of 32 shifted limb arrays times
+    limbs (fp._conv's shape) is bounded by 32 products: proven at
+    exactly 32 * 4095^2, and flagged against a bound one below."""
+    src_t = (
+        "# graftlint: kernel-module dtype=int32\n"
+        "import jax.numpy as jnp\n\n"
+        "# graftlint: kernel bounds=(limb, limb) -> <={hi}; domain=any\n"
+        "def conv(a, b):\n"
+        "    s = jnp.stack([b[i:] for i in range(32)])\n"
+        "    return (a[:, None] * s).sum(axis=0)\n"
+    )
+    rel = "tests/fixtures/graftlint/virtual_stack_sum.py"
+    bound = 32 * 4095 * 4095
+    ok = lint_source(src_t.format(hi=bound), rel)
+    assert [f for f in ok if f.rule == "GL09"] == []
+    bad = [f for f in lint_source(src_t.format(hi=bound - 1), rel)
+           if f.rule == "GL09"]
+    assert len(bad) == 1 and f"[0, {bound}]" in bad[0].message, \
+        [f.render() for f in bad]
+
+
 def test_gl09_scan_accumulator_bound_is_derived_not_assumed():
     """Tightening normalize's declared input below the derived scan
     bound (~1.078e9) must flag mont_mul's call into it — proof that

@@ -1387,8 +1387,10 @@ class Interp:
         out = self._interval_op(a, op, b)
         out = self._domain_op(node, a, op, b, out)
         if not isinstance(op, ast.MatMult):
-            # elementwise ops keep the limb axis (broadcast included)
-            out = replace(out, limbaxis=a.limbaxis or b.limbaxis)
+            # elementwise ops keep the limb axis (broadcast included),
+            # and a stack's leading axis against an operand of its rank
+            out = replace(out, limbaxis=a.limbaxis or b.limbaxis,
+                          scanlen=a.scanlen or b.scanlen)
         if self.check:
             self.an.check_overflow(
                 node, out,
@@ -1636,16 +1638,25 @@ class Interp:
                     "flatten", "squeeze"):
             return replace(base, limbaxis=False, scanlen=None)
         if meth == "sum":
-            return self._reduce_sum(node, base)
+            return self._reduce_sum(node, base, 0)
         if meth in ("max", "min"):
             return replace(base, limbaxis=False, scanlen=None)
         if meth in ("item", "tolist"):
             return UNKNOWN
         return TOPV
 
-    def _reduce_sum(self, node, x):
+    def _reduce_sum(self, node, x, axis_pos=None):
         a = to_av(x)
-        if a.bounded and a.limbaxis:
+        if axis_pos is not None and _names_axis0(node, axis_pos):
+            # a sum over a stack's leading axis adds its scanlen terms
+            if a.bounded and a.scanlen:
+                out = AV(min(a.lo, 0) * a.scanlen, max(a.hi, 0) * a.scanlen,
+                         a.dom, prov=f"sum of {a.scanlen} stacked terms "
+                                     f"each {a.desc()}")
+                if self.check:
+                    self.an.check_overflow(node, out, "stacked sum")
+                return out
+        elif a.bounded and a.limbaxis:
             out = AV(a.lo * N_LIMBS if a.lo < 0 else 0,
                      a.hi * N_LIMBS, a.dom,
                      prov=f"sum of {N_LIMBS} limbs each {a.desc()}")
@@ -2106,7 +2117,27 @@ def _i_bool(interp, node, args, kwargs):
 
 
 def _i_sum(interp, node, args, kwargs):
-    return interp._reduce_sum(node, args[0]) if args else TOPV
+    return interp._reduce_sum(node, args[0], 1) if args else TOPV
+
+
+def _names_axis0(node, pos):
+    """True iff the sum call ``node`` reduces axis 0, given as
+    ``axis=0`` or as its ``pos``-th positional argument."""
+    axis = next((k.value for k in node.keywords if k.arg == "axis"), None)
+    if axis is None and len(node.args) > pos:
+        axis = node.args[pos]
+    return isinstance(axis, ast.Constant) and axis.value == 0
+
+
+def _i_stack(interp, node, args, kwargs):
+    """jnp.stack of a sequence of known length k along axis 0: the
+    result's leading axis has k entries, which a sum over axis 0 adds."""
+    out = _i_join_seq(interp, node, args, kwargs)
+    axis = kwargs.get("axis", args[1] if len(args) > 1 else Conc(0))
+    if isinstance(out, AV) and args and isinstance(args[0], AbsTuple) \
+            and is_known_conc(axis) and axis.value == 0:
+        return replace(out, scanlen=len(args[0]))
+    return out
 
 
 def _einsum_contracts_last_axis(spec: str, arrays: list) -> bool:
@@ -2203,7 +2234,7 @@ def _i_partial(interp, node, args, kwargs):
 
 _INTRINSICS = {
     "jnp.asarray": _i_asarray, "jnp.array": _i_asarray,
-    "jnp.stack": _i_join_seq, "jnp.concatenate": _i_join_seq,
+    "jnp.stack": _i_stack, "jnp.concatenate": _i_join_seq,
     "jnp.hstack": _i_join_seq, "jnp.vstack": _i_join_seq,
     "jnp.where": _i_where,
     "jnp.zeros": _i_zeros, "jnp.zeros_like": _i_zeros,
@@ -2239,6 +2270,9 @@ _INTRINSICS = {
     "lax.dot_general": _i_matmul,
     "jax.jit": _i_first, "jit": _i_first,
     "jax.vmap": _i_first, "vmap": _i_first,
+    # a layout constraint moves no value: it fixes only the physical
+    # order of the array's dimensions
+    "with_layout_constraint": _i_first,
     "jax.ensure_compile_time_eval": _i_unknown,
     "pl.pallas_call": _i_pallas, "pltpu.pallas_call": _i_pallas,
     "pallas_call": _i_pallas,
